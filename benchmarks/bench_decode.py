@@ -42,6 +42,7 @@ from repro.cluster.api import (
     STATUS_TIMEOUT,
 )
 from repro.configs import get_reduced
+from repro.launch.mesh import make_data_mesh
 from repro.models.transformer import Model, init_params
 from repro.obs import (
     decode_timeline,
@@ -335,8 +336,7 @@ def run(chain_sweep=(1, 4, 8), shard_sweep=(4, 8), requests: int = 40,
         for shards in shard_sweep:
             if shards > n_dev or chains % shards:
                 continue
-            mesh = jax.make_mesh((shards,), ("data",),
-                                 devices=jax.devices()[:shards])
+            mesh = make_data_mesh(shards)
             eng = DecodeEngine(model=model, params=_bank(cfg, chains, seed),
                                max_seq=max_seq, mesh=mesh)
             rows.append(_measure(eng, **kw))

@@ -26,6 +26,7 @@ import numpy as np
 from repro.analysis import instrument
 from repro.cluster import ServeEngine, bucket_size
 from repro.core import PolyRegression
+from repro.launch.mesh import make_data_mesh
 from repro.models import regression_predict
 from repro.obs import registry
 
@@ -98,8 +99,7 @@ def run(chain_sweep=(8, 64, 256), shard_sweep=(2, 4, 8), requests: int = 200,
     for shards in shard_sweep:
         if shards > n_dev or chains % shards:
             continue
-        mesh = jax.make_mesh((shards,), ("data",),
-                             devices=jax.devices()[:shards])
+        mesh = make_data_mesh(shards)
         eng = ServeEngine(predict_fn=predict,
                           params=_bank(reg, chains, seed), mesh=mesh)
         rows.append(_measure(eng, requests=requests, max_queries=max_queries,

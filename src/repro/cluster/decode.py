@@ -111,7 +111,6 @@ class DecodeEngine(BankEngine):
     chain_axis: str = "data"
     shard_params: bool = False
     fused: bool = False
-    fused_interpret: Optional[bool] = None  # default: compiled only on TPU
     return_logits: bool = False
     max_cache_rungs: int = 8
 
@@ -123,8 +122,7 @@ class DecodeEngine(BankEngine):
         self._init_bank("DecodeEngine")
         cfg = self.model.cfg if hasattr(self.model, "cfg") else self.model
         self._model = Model(cfg, mesh=None, remat=False,
-                            decode_fused=self.fused,
-                            decode_interpret=self.fused_interpret)
+                            decode_fused=self.fused)
         self._model._require_stacked_attention("DecodeEngine")
         self._cache: OrderedDict = OrderedDict()  # B rung -> KV-cache bank
         reg = _registry()

@@ -10,8 +10,8 @@ Delays are *endogenous*: the scan input is the schedule's per-chain
 ``read_versions`` and the jitted body derives staleness as
 ``server_version - read_version`` from the carried commit counter, so the
 device executes the worker schedule instead of consuming a staleness
-side-channel.  With ``mesh=`` the chunk body runs under the repo's
-``shard_map`` compat shim with chains split over the ``data`` axis — pure
+side-channel.  With ``mesh=`` the chunk body runs under
+``jax.shard_map`` with chains split over the ``data`` axis — pure
 SPMD, no cross-chain communication, so per-chain trajectories are identical
 sharded or not.
 
@@ -65,7 +65,7 @@ from repro.obs.trace import span as _span
 from repro.samplers.base import Sampler, SamplerState
 from repro.samplers.transforms import MaskedBatch
 from repro.train.engine import Hook, drive_chunks
-from repro.utils import SHARD_MAP_CHECK_KW, bucket_size, shard_map
+from repro.utils import bucket_size
 
 PyTree = Any
 BatchFn = Callable[[jax.Array], PyTree]  # key -> one chain's batch (pure jax)
@@ -318,10 +318,10 @@ class ClusterEngine:
         if self.mesh is not None:
             ax = self.chain_axis
             batch_spec = P(None, ax) if batch_axis == 0 else P()
-            chunk = shard_map(chunk, mesh=self.mesh,
-                              in_specs=(P(ax), batch_spec, P(None, ax)),
-                              out_specs=(P(ax), P(None, ax)),
-                              **SHARD_MAP_CHECK_KW)
+            chunk = jax.shard_map(chunk, mesh=self.mesh,
+                                  in_specs=(P(ax), batch_spec, P(None, ax)),
+                                  out_specs=(P(ax), P(None, ax)),
+                                  check_vma=False)
         return jax.jit(chunk, donate_argnums=(0,) if self.donate else ())
 
     def _build_masked_chunk(self, pad: int):
@@ -352,10 +352,10 @@ class ClusterEngine:
 
         if self.mesh is not None:
             ax = self.chain_axis
-            chunk = shard_map(chunk, mesh=self.mesh,
-                              in_specs=(P(ax), P(), P(None, ax)),
-                              out_specs=(P(ax), P(None, ax)),
-                              **SHARD_MAP_CHECK_KW)
+            chunk = jax.shard_map(chunk, mesh=self.mesh,
+                                  in_specs=(P(ax), P(), P(None, ax)),
+                                  out_specs=(P(ax), P(None, ax)),
+                                  check_vma=False)
         return jax.jit(chunk, donate_argnums=(0,) if self.donate else ())
 
     def _run_masked_chunk(self, state, data, extra, pad: int):
@@ -367,14 +367,28 @@ class ClusterEngine:
     # -- init -----------------------------------------------------------------
     def init(self, params: PyTree, key: jax.Array, *,
              jitter: float = 0.0) -> SamplerState:
-        """C-chain ensemble state; chain ``c``'s key is ``split(key, C)[c]``."""
-        state = init_ensemble(self.sampler, params, key,
-                              num_chains=self.num_chains, jitter=jitter)
-        if self.mesh is not None:
-            sharding = jax.sharding.NamedSharding(self.mesh, P(self.chain_axis))
-            state = jax.tree_util.tree_map(
-                lambda x: jax.device_put(x, sharding), state)
-        return state
+        """C-chain ensemble state; chain ``c``'s key is ``split(key, C)[c]``.
+
+        With a mesh the state is built in its sharded layout: each device
+        computes only its own chains, so no device ever holds the whole
+        ensemble."""
+        def init(params, key):
+            return init_ensemble(self.sampler, params, key,
+                                 num_chains=self.num_chains, jitter=jitter)
+
+        if self.mesh is None:
+            return init(params, key)
+        sharding = jax.sharding.NamedSharding(self.mesh, P(self.chain_axis))
+        return jax.jit(init, out_shardings=sharding)(params, key)
+
+    def lower_chunk(self, state: SamplerState, batches: PyTree, extra: dict):
+        """Lower the chunk program :meth:`run` dispatches for per-chain
+        batches (generated, or explicit with ``per_chain_batches``): one
+        chunk's ``batches`` ``(n, C, ...)`` and ``extra`` (``{"rv": (n, C)
+        int32}`` read versions for a fault-free schedule), as arrays or
+        ``jax.ShapeDtypeStruct``\\ s.  ``.compile()`` of the result gives
+        the program's ``memory_analysis()`` and its compiled text."""
+        return self._chunk_per_chain.lower(state, batches, extra)
 
     # -- state export ---------------------------------------------------------
     def save_ensemble(self, state: SamplerState, path: str) -> None:

@@ -154,7 +154,6 @@ class PagedDecodeEngine(BankEngine):
     chain_axis: str = "data"
     shard_params: bool = False
     fused: bool = False
-    fused_interpret: Optional[bool] = None  # default: compiled only on TPU
     return_logits: bool = False
     max_waiting: Optional[int] = None  # submit() backpressure bound
 
@@ -166,8 +165,7 @@ class PagedDecodeEngine(BankEngine):
         self._init_bank("PagedDecodeEngine")
         cfg = self.model.cfg if hasattr(self.model, "cfg") else self.model
         self._model = Model(cfg, mesh=None, remat=False,
-                            decode_fused=self.fused,
-                            decode_interpret=self.fused_interpret)
+                            decode_fused=self.fused)
         self._model._require_paged("PagedDecodeEngine")
         if self.max_seq % self.page_size:
             raise ValueError(
@@ -461,6 +459,14 @@ class PagedDecodeEngine(BankEngine):
         self._m_occupancy.set(used / self.num_slots)
         self._m_pages.set(
             1.0 - self._allocator.free_pages / (self.num_pages - 1))
+
+    def lower_step(self):
+        """Lower the engine's one decode-step program at its current slot
+        state; ``.compile()`` of the result gives its compiled text and
+        ``memory_analysis()``."""
+        return self._step_fn.lower(
+            self.params, self._pages, self._tables, self._positions,
+            self._remaining, self._last_tok, self._keys, self._greedy)
 
     @property
     def num_active(self) -> int:

@@ -1,25 +1,28 @@
-"""Pallas TPU kernel: fused streaming decode step (the per-token hot path).
+"""Pallas TPU kernels: fused streaming decode steps (the per-token hot path).
 
-One decode step of the chain-bank BMA server touches, per (chain, batch) row:
-the new token's k/v written into the ring-cache slot for the current
-position, then single-query attention over the whole cache.  Unfused, XLA
-emits (write k slot), (write v slot), (read k cache), (read v cache): four
-HBM round trips of the (smax, KV, hd) cache per layer.  This kernel fuses
-the slot update with the attention read — the cache streams through VMEM
-exactly once per operand and the updated slot never round-trips to HBM
-before being attended over.
+One decode step of the chain-bank BMA server, per (chain, batch) row: the
+new token's k/v is written into the cache at the current position, then
+single-query attention runs over the cache.  Unfused, XLA emits (write k),
+(write v), (read k cache), (read v cache).  These kernels fuse the write
+with the attention read: the cache streams through VMEM once per operand,
+and only the new row is written back to HBM.
 
-Layout: the grid walks batch rows (the vmapped chain axis of a
-:class:`~repro.cluster.decode.DecodeEngine` batches into extra grid
-dimensions via the pallas batching rule, so a (C, B) bank is a (C, B) grid);
-each step holds one ``(smax, KV, hd)`` cache tile per operand in VMEM —
-1 MiB at (1024, 8, 128) bf16, three tiles resident well inside ~16 MiB.  The
-slot select is the same broadcast-compare + select idiom as
-``delay_gather``; masking arrives precomputed as a ``(smax,)`` validity
-vector so the kernel stays free of position arithmetic.  On TPU, ``hd``
-should be a multiple of 128 lanes and ``smax`` of 8 sublanes;
-``interpret=True`` (the CPU default, matching the other kernels) has no
-tiling constraints.
+- :func:`decode_step_2d` serves the contiguous ring cache
+  ``(B, smax, KV, hd)``.  The grid walks ``(row, KV block)``: each step
+  holds one ``(bs, KV, hd)`` block per operand (bs <= 512, 1 MiB in bf16 at
+  KV 8, hd 128), so VMEM use does not grow with smax.
+- :func:`paged_decode_step` serves a paged pool ``(n_pages, page_size, KV,
+  hd)`` shared by every slot.  The page table and positions arrive by
+  scalar prefetch; the kernel DMAs the slot's pages, a block of pages at a
+  time, and stops at the block holding the slot's position.
+
+Both attend with an online softmax over KV blocks (running max, running
+sum, fp32 accumulator), which ``ref.py`` mirrors block for block.  Scores
+for all query heads against one block are one ``(H, hd) x (bs*KV, hd)^T``
+matmul; a head mask keeps query head ``n*G + g`` on its own kv head ``n``.
+The new row is overlaid on the block in VMEM, so the result does not depend
+on whether the read of that block saw the row's write.  Softmax and
+accumulation are fp32 and the output is rounded to q's dtype once.
 """
 
 from __future__ import annotations
@@ -30,163 +33,313 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import on_backend
 
 NEG_INF = -1e30
+BLOCK_KV = 512  # cache rows per online-softmax block
+
+__all__ = ["decode_step_2d", "paged_decode_step", "kv_block", "attend_block",
+           "init_carry", "finish"]
 
 
-__all__ = ["decode_step_2d", "paged_decode_step"]
+def kv_block(n: int) -> int:
+    """Rows per KV block for ``n`` cache rows: ``n`` itself when it is at
+    most :data:`BLOCK_KV`, else the largest power-of-two divisor of ``n``
+    up to :data:`BLOCK_KV` (a block never overhangs the cache)."""
+    if n <= BLOCK_KV:
+        return n
+    b = BLOCK_KV
+    while n % b:
+        b //= 2
+    return b
 
 
-def _kernel(q_ref, kn_ref, vn_ref, kc_ref, vc_ref, valid_ref, slot_ref,
-            o_ref, ko_ref, vo_ref):
-    _, smax, KV, hd = kc_ref.shape
-    scale = 1.0 / math.sqrt(hd)
-    slot = slot_ref[0]
-    # in-VMEM slot update: broadcast-compare + select (delay_gather idiom)
-    sel = jax.lax.broadcasted_iota(jnp.int32, (smax, KV, hd), 0) == slot
-    k = jnp.where(sel, kn_ref[0][None], kc_ref[0])
-    v = jnp.where(sel, vn_ref[0][None], vc_ref[0])
-    ko_ref[0] = k
-    vo_ref[0] = v
-    # single-query attention over the updated cache, fp32 softmax
-    q32 = q_ref[0].astype(jnp.float32) * scale            # (KV, G, hd)
-    s = jnp.einsum("ngh,cnh->ngc", q32, k.astype(jnp.float32))
-    s = jnp.where(valid_ref[...][None, None, :] == 1, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("ngc,cnh->ngh", p, v.astype(jnp.float32))
-    o_ref[0] = o.astype(o_ref.dtype)
+def init_carry(H: int, hd: int):
+    """Online-softmax state (running max, running sum, accumulator)."""
+    return (jnp.full((H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, hd), jnp.float32))
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def decode_step_2d(q, k_new, v_new, k_cache, v_cache, valid, slot,
-                   *, interpret=True):
+def attend_block(q32, k, v, keep_rows, carry):
+    """One KV block of single-query attention, all heads at once.
+
+    q32: (H, hd) fp32, already scaled; k, v: (T, KV, hd); keep_rows:
+    (1, T*KV) bool, whether each cache row may be attended (repeated over
+    the KV heads of the row); carry: :func:`init_carry` state.
+    """
+    T, KV, hd = k.shape
+    H = q32.shape[0]
+    m, l, acc = carry
+    k2 = k.astype(jnp.float32).reshape(T * KV, hd)
+    v2 = v.astype(jnp.float32).reshape(T * KV, hd)
+    r = jax.lax.broadcasted_iota(jnp.int32, (H, T * KV), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (H, T * KV), 1)
+    keep = (r // (H // KV) == c % KV) & keep_rows
+    s = jax.lax.dot_general(q32, k2, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.where(keep, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m - m_new)
+    l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+    acc = alpha * acc + jnp.dot(p, v2, preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def finish(carry, dtype):
+    """Normalized attention output (H, hd) in ``dtype``."""
+    _, l, acc = carry
+    return (acc / l).astype(dtype)
+
+
+def _overlay(block, row, at):
+    """``block`` (T, KV, hd) with row ``at`` (block-relative; may be out of
+    range) replaced by ``row`` (KV, hd)."""
+    t = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0)
+    return jnp.where(t == at, row[None], block)
+
+
+def _chain_batched(chains, n_shared: int = 0):
+    """Turn ``chains(*args)`` — a kernel whose operands carry a leading
+    chain axis, except the first ``n_shared`` ones — into a single-chain
+    function whose ``vmap`` over chains calls ``chains`` once.
+
+    The pallas batching rule would add the chain axis to the grid, but a
+    TPU kernel cannot take a batched block of an HBM (``pl.ANY``) operand.
+    Under ``vmap`` unbatched operands are broadcast along the chain axis;
+    batched shared operands (per-chain page tables) fall back to one call
+    per chain.
+    """
+
+    @jax.custom_batching.custom_vmap
+    def one(*args):
+        out = chains(*args[:n_shared], *(a[None] for a in args[n_shared:]))
+        return tuple(o[0] for o in out)
+
+    @one.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        if any(in_batched[:n_shared]):
+            def per_chain(xs):
+                return one(*xs)
+            full = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                    for a, b in zip(args, in_batched)]
+            out = jax.lax.map(per_chain, full)
+        else:
+            rest = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                    for a, b in zip(args[n_shared:], in_batched[n_shared:])]
+            out = chains(*args[:n_shared], *rest)
+        return tuple(out), (True,) * len(out)
+
+    return one
+
+
+# ---------------------------------------------------------------------------
+# contiguous ring cache
+# ---------------------------------------------------------------------------
+def _kernel(slot_ref, q_ref, kn_ref, vn_ref, kc_ref, vc_ref, valid_ref,
+            ko_hbm, vo_hbm, o_ref, m_sc, l_sc, acc_sc, sem):
+    c, b, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    bs = kc_ref.shape[2]
+    slot = slot_ref[c]
+    writes = [pltpu.make_async_copy(
+        src, dst.at[pl.ds(c, 1), pl.ds(b, 1), pl.ds(slot, 1)], sem.at[i])
+        for i, (src, dst) in enumerate(((kn_ref, ko_hbm), (vn_ref, vo_hbm)))]
+
+    @pl.when(j == 0)
+    def _():
+        # the only HBM write of the step: the new row into its slot
+        for w in writes:
+            w.start()
+        m0, l0, a0 = init_carry(*acc_sc.shape)
+        m_sc[...], l_sc[...], acc_sc[...] = m0, l0, a0
+
+    hd = q_ref.shape[-1]
+    q32 = q_ref[0, 0].astype(jnp.float32) * (1.0 / math.sqrt(hd))
+    k = _overlay(kc_ref[0, 0], kn_ref[0, 0, 0], slot - j * bs)
+    v = _overlay(vc_ref[0, 0], vn_ref[0, 0, 0], slot - j * bs)
+    carry = attend_block(q32, k, v, valid_ref[0] != 0,
+                         (m_sc[...], l_sc[...], acc_sc[...]))
+    m_sc[...], l_sc[...], acc_sc[...] = carry
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        o_ref[0, 0] = finish(carry, o_ref.dtype)
+        for w in writes:
+            w.wait()
+
+
+def _call(q, k_new, v_new, k_cache, v_cache, valid, slot, *,
+          interpret: bool):
+    C, B, H, hd = q.shape
+    smax, KV = k_cache.shape[2:4]
+    bs = kv_block(smax)
+    row = lambda c, b, j, *_: (c, b, 0, 0, 0)  # noqa: E731
+    blk = lambda c, b, j, *_: (c, b, j, 0, 0)  # noqa: E731
+    head = pl.BlockSpec((1, 1, H, hd), lambda c, b, j, *_: (c, b, 0, 0))
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(C, B, smax // bs),
+            in_specs=[
+                head,
+                pl.BlockSpec((1, 1, 1, KV, hd), row),
+                pl.BlockSpec((1, 1, 1, KV, hd), row),
+                pl.BlockSpec((1, 1, bs, KV, hd), blk),
+                pl.BlockSpec((1, 1, bs, KV, hd), blk),
+                pl.BlockSpec((1, 1, bs * KV), lambda c, b, j, *_: (c, 0, j)),
+            ],
+            out_specs=[any_, any_, head],
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, hd), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
+                   jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        input_output_aliases={4: 0, 5: 1},  # caches update in place
+        interpret=interpret,
+    )(slot, q, k_new[:, :, None], v_new[:, :, None], k_cache, v_cache, valid)
+
+
+_decode_chains = _chain_batched(lambda *a: on_backend(_call, *a))
+
+
+@jax.jit
+def decode_step_2d(q, k_new, v_new, k_cache, v_cache, valid, slot):
     """q: (B, KV, G, hd); k_new, v_new: (B, KV, hd);
     k_cache, v_cache: (B, smax, KV, hd); valid: (smax,) int32 (1 = attend);
     slot: (1,) int32 — the ring slot the new k/v lands in.
 
     Returns (o (B, KV, G, hd) in q.dtype, k_cache', v_cache') with the slot
-    row replaced in both caches (aliased in place).
+    row replaced in both caches (aliased in place).  A chain-vmapped caller
+    gets one kernel over a ``(chain, row, KV block)`` grid.
     """
     B, KV, G, hd = q.shape
-    smax = k_cache.shape[1]
-    return pl.pallas_call(
-        _kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, smax, KV, hd), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, smax, KV, hd), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((smax,), lambda _i: (0,)),
-            pl.BlockSpec(memory_space=pl.ANY),  # slot scalar
-        ],
-        out_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, smax, KV, hd), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, smax, KV, hd), lambda i: (i, 0, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-        ],
-        input_output_aliases={3: 1, 4: 2},  # caches update in place
-        interpret=interpret,
-    )(q, k_new, v_new, k_cache, v_cache, valid, slot)
+    # (1, smax*KV): a unit row dim keeps the block legal under the chain axis
+    rows = jnp.repeat(jnp.asarray(valid, jnp.int32), KV)[None]
+    kc, vc, o = _decode_chains(
+        q.reshape(B, KV * G, hd), k_new.astype(k_cache.dtype),
+        v_new.astype(v_cache.dtype), k_cache, v_cache, rows,
+        jnp.asarray(slot, jnp.int32).reshape(()))
+    return o.reshape(B, KV, G, hd), kc, vc
 
 
 # ---------------------------------------------------------------------------
-# paged variant: page-table gather over a shared block pool
+# paged pool: page-table DMA gather
 # ---------------------------------------------------------------------------
-def _paged_kernel(tbl_ref, pos_ref, q_ref, kn_ref, vn_ref, kc_ref, vc_ref,
-                  o_ref, ko_ref, vo_ref):
-    _, ps, KV, hd = kc_ref.shape
+def _paged_kernel(tbl_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_hbm, vp_hbm,
+                  ko_hbm, vo_hbm, o_ref, kbuf, vbuf, sem, wsem, *,
+                  pages_per_block: int):
+    # ko_hbm / vo_hbm alias kp_hbm / vp_hbm: pages are read through the
+    # inputs and the new rows written through the outputs
+    c, s = pl.program_id(0), pl.program_id(1)
     maxp = tbl_ref.shape[1]
-    scale = 1.0 / math.sqrt(hd)
-    pos = pos_ref[0]
+    ps, KV, hd = kbuf.shape[1:]
+    width = pages_per_block * ps
+    pos = pos_ref[s]
     # store the new k/v into this slot's page for the current position; only
-    # these two rows of the shared pool are touched (partial store on the
-    # aliased output), every other page survives bit-for-bit
-    pg = tbl_ref[0, pos // ps]
-    off = pos % ps
-    ko_ref[pl.ds(pg, 1), pl.ds(off, 1)] = kn_ref[0][None, None]
-    vo_ref[pl.ds(pg, 1), pl.ds(off, 1)] = vn_ref[0][None, None]
+    # these two rows of the shared pool are touched, every other page
+    # survives bit-for-bit
+    page, off = tbl_ref[s, pos // ps], pos % ps
+    writes = [pltpu.make_async_copy(
+        src, dst.at[pl.ds(c, 1), pl.ds(page, 1), pl.ds(off, 1)], wsem.at[i])
+        for i, (src, dst) in enumerate(((kn_ref, ko_hbm), (vn_ref, vo_hbm)))]
+    for w in writes:
+        w.start()
 
-    # gather this slot's pages in *logical* order — the attention result is
-    # invariant to how the allocator permuted the physical pages
-    def gather(j, acc):
-        ka, va = acc
-        page = tbl_ref[0, j]
-        kt = kc_ref[pl.ds(page, 1)][0]
-        vt = vc_ref[pl.ds(page, 1)][0]
-        return (jax.lax.dynamic_update_index_in_dim(ka, kt, j, 0),
-                jax.lax.dynamic_update_index_in_dim(va, vt, j, 0))
+    q32 = q_ref[0, 0].astype(jnp.float32) * (1.0 / math.sqrt(hd))
 
-    zero = jnp.zeros((maxp, ps, KV, hd), kc_ref.dtype)
-    k_all, v_all = jax.lax.fori_loop(0, maxp, gather, (zero, zero))
-    # overlay the new row at its logical position: the gather may observe the
-    # pool before or after this step's store (the output aliases the input),
-    # and the select makes both orders produce identical attention inputs
-    sel = jax.lax.broadcasted_iota(jnp.int32, (maxp * ps, KV, hd), 0) == pos
-    k = jnp.where(sel, kn_ref[0][None], k_all.reshape(maxp * ps, KV, hd))
-    v = jnp.where(sel, vn_ref[0][None], v_all.reshape(maxp * ps, KV, hd))
-    # single-query attention over the gathered logical window, fp32 softmax
-    # (the same math as _kernel; validity is positional: logical index <= pos)
-    q32 = q_ref[0].astype(jnp.float32) * scale            # (KV, G, hd)
-    s = jnp.einsum("ngh,cnh->ngc", q32, k.astype(jnp.float32))
-    valid = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) <= pos
-    s = jnp.where(valid, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("ngc,cnh->ngh", p, v.astype(jnp.float32))
-    o_ref[0] = o.astype(o_ref.dtype)
+    def block(blk, carry):
+        # gather this slot's pages in *logical* order (the attention result
+        # is invariant to how the allocator permuted the physical pages);
+        # entries past the slot's pages name real pages (the garbage page),
+        # so the buffer never holds stale data
+        copies = []
+        for i in range(pages_per_block):
+            pg = tbl_ref[s, jnp.minimum(blk * pages_per_block + i, maxp - 1)]
+            copies += [
+                pltpu.make_async_copy(kp_hbm.at[c, pg], kbuf.at[i], sem),
+                pltpu.make_async_copy(vp_hbm.at[c, pg], vbuf.at[i], sem)]
+        for cp in copies:
+            cp.start()
+        for cp in copies:
+            cp.wait()
+        base = blk * width
+        k = _overlay(kbuf[...].reshape(width, KV, hd), kn_ref[0, 0, 0],
+                     pos - base)
+        v = _overlay(vbuf[...].reshape(width, KV, hd), vn_ref[0, 0, 0],
+                     pos - base)
+        t = jax.lax.broadcasted_iota(jnp.int32, (1, width * KV), 1) // KV
+        return attend_block(q32, k, v, t + base <= pos, carry)
+
+    carry = jax.lax.fori_loop(0, pos // width + 1, block,
+                              init_carry(q_ref.shape[2], hd))
+    o_ref[0, 0] = finish(carry, o_ref.dtype)
+    for w in writes:
+        w.wait()
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_step(q, k_new, v_new, k_pages, v_pages, tables, pos,
-                      *, interpret=True):
+def _paged_call(tables, pos, q, k_new, v_new, k_pages, v_pages, *,
+                interpret: bool):
+    C, S, H, hd = q.shape
+    ps, KV = k_pages.shape[2:4]
+    maxp = tables.shape[1]
+    ppb = max(1, min(maxp, BLOCK_KV // ps))
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    row = lambda c, s, *_: (c, s, 0, 0, 0)  # noqa: E731
+    head = pl.BlockSpec((1, 1, H, hd), lambda c, s, *_: (c, s, 0, 0))
+    return pl.pallas_call(
+        partial(_paged_kernel, pages_per_block=ppb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(C, S),
+            in_specs=[head,
+                      pl.BlockSpec((1, 1, 1, KV, hd), row),
+                      pl.BlockSpec((1, 1, 1, KV, hd), row),
+                      any_, any_],
+            out_specs=[any_, any_, head],
+            scratch_shapes=[pltpu.VMEM((ppb, ps, KV, hd), k_pages.dtype),
+                            pltpu.VMEM((ppb, ps, KV, hd), v_pages.dtype),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
+                   jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        input_output_aliases={5: 0, 6: 1},  # pools update in place
+        interpret=interpret,
+    )(tables, pos, q, k_new[:, :, None], v_new[:, :, None], k_pages, v_pages)
+
+
+_paged_chains = _chain_batched(lambda *a: on_backend(_paged_call, *a),
+                               n_shared=2)
+
+
+@jax.jit
+def paged_decode_step(q, k_new, v_new, k_pages, v_pages, tables, pos):
     """Fused paged decode step: slot-table gather + slot write + attention.
 
     q: (S, KV, G, hd); k_new, v_new: (S, KV, hd); k_pages, v_pages:
     (n_pages, page_size, KV, hd) — the block pool **shared by every slot**;
     tables: (S, maxp) int32 per-slot page table (logical page j of slot i
-    lives in physical page ``tables[i, j]``); pos: (S,) int32 absolute
-    position the new token is written at (and the highest logical index
-    attended — validity is ``logical index <= pos``, full attention only).
+    lives in physical page ``tables[i, j]``, every entry a valid page);
+    pos: (S,) int32 absolute position the new token is written at (and the
+    highest logical index attended — validity is ``logical index <= pos``,
+    full attention only).
 
     Returns (o (S, KV, G, hd) in q.dtype, k_pages', v_pages') with exactly
     one ``(page, offset)`` row per slot replaced in each pool (aliased in
-    place).  The grid walks slots; a chain-vmapped engine batches the pool
-    into extra grid dimensions via the pallas batching rule.
+    place).  The grid walks slots; a chain-vmapped engine (pools batched,
+    tables and positions shared) gets one kernel over a ``(chain, slot)``
+    grid.
     """
     S, KV, G, hd = q.shape
-    maxp = tables.shape[1]
-    return pl.pallas_call(
-        _paged_kernel,
-        grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, maxp), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1, KV, G, hd), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda i: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # shared k pool
-            pl.BlockSpec(memory_space=pl.ANY),  # shared v pool
-        ],
-        out_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, KV, G, hd), q.dtype),
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ],
-        input_output_aliases={5: 1, 6: 2},  # pools update in place
-        interpret=interpret,
-    )(tables, pos, q, k_new, v_new, k_pages, v_pages)
+    kp, vp, o = _paged_chains(
+        jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
+        q.reshape(S, KV * G, hd), k_new.astype(k_pages.dtype),
+        v_new.astype(v_pages.dtype), k_pages, v_pages)
+    return o.reshape(S, KV, G, hd), kp, vp

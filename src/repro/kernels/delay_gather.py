@@ -13,11 +13,11 @@ shape since depth = tau+1 is small (<= 8 in fidelity runs).
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import on_backend
 
 BLOCK = 4096  # lanes per grid step (32 sublanes x 128 lanes fp32)
 
@@ -29,12 +29,8 @@ def _kernel(hist_ref, slot_ref, o_ref):
     o_ref[...] = jnp.sum(hist_ref[...] * sel, axis=0)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def delay_gather_1d(history, slots, *, interpret=True):
-    """history: (depth, N) float32; slots: (N,) int32 in [0, depth).
-    N % BLOCK == 0.  Returns (N,) gathered values."""
+def _call(history, slots, *, interpret: bool):
     depth, N = history.shape
-    assert N % BLOCK == 0, N
     grid = (N // BLOCK,)
     return pl.pallas_call(
         _kernel,
@@ -47,3 +43,12 @@ def delay_gather_1d(history, slots, *, interpret=True):
         out_shape=jax.ShapeDtypeStruct((N,), history.dtype),
         interpret=interpret,
     )(history, slots)
+
+
+@jax.jit
+def delay_gather_1d(history, slots):
+    """history: (depth, N) float32; slots: (N,) int32 in [0, depth).
+    N % BLOCK == 0.  Returns (N,) gathered values."""
+    if history.shape[1] % BLOCK:
+        raise ValueError(f"N={history.shape[1]} is not a multiple of {BLOCK}")
+    return on_backend(_call, history, slots)

@@ -6,10 +6,15 @@ the full parameter vector.  This kernel generates the Langevin noise *in
 VMEM* (counter-based threefry, rng.py) and fuses the update: one read of
 (x, g), one write of x'.
 
-Tiling: flat parameters are padded/reshaped by ops.py to (rows, LANES=128·k);
-the grid walks row blocks of 256 rows x 1024 lanes (1 MiB fp32 per operand —
-3 operands resident = 3 MiB of ~16 MiB VMEM, leaving room for double
-buffering).
+Layout: any 2-D view ``(R, C)`` of a parameter leaf in its own dtype (ops.py
+passes ``(prod(leading dims), last dim)``, so nothing is padded or cast in
+HBM).  The grid walks ``(256, 1024)`` blocks — 1 MiB per operand in fp32,
+double-buffered well inside the default scoped VMEM — and Pallas masks a
+partial edge block.  The noise counter of element ``(r, c)`` is its
+row-major index ``r*C + c``, the flat index of the leaf, so the noise does
+not depend on the view or the blocking.  The arithmetic is fp32 whatever
+the leaf dtype; the result is rounded to the leaf dtype once.  The seed
+and the two scalars arrive by scalar prefetch (SMEM).
 """
 
 from __future__ import annotations
@@ -19,49 +24,56 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import on_backend
 from repro.kernels.rng import normal_from_counter
 
 BLOCK_ROWS = 256
-LANES = 1024
+LANES = 1024  # block columns
 
 
-def _kernel(x_ref, g_ref, seed_ref, gamma_ref, scale_ref, o_ref):
-    i = pl.program_id(0)
-    rows, lanes = x_ref.shape
-    # global element counter for this block
-    base = (i * rows * lanes).astype(jnp.uint32) if hasattr(
-        i, "astype") else jnp.uint32(i * rows * lanes)
-    row_ids = jax.lax.broadcasted_iota(jnp.uint32, (rows, lanes), 0)
-    lane_ids = jax.lax.broadcasted_iota(jnp.uint32, (rows, lanes), 1)
-    counter = base + row_ids * jnp.uint32(lanes) + lane_ids
-    xi = normal_from_counter(seed_ref[0], seed_ref[1], counter)
-    gamma = gamma_ref[0]
-    scale = scale_ref[0]
-    o_ref[...] = x_ref[...] - gamma * g_ref[...] + scale * xi
+def _kernel(seed_ref, coef_ref, x_ref, g_ref, o_ref, *, width: int):
+    rows, cols = x_ref.shape
+    i, j = pl.program_id(0), pl.program_id(1)
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) + i * rows
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) + j * cols
+    xi = normal_from_counter(seed_ref[0], seed_ref[1], r * width + c)
+    gamma, scale = coef_ref[0], coef_ref[1]
+    x = x_ref[...].astype(jnp.float32)
+    g = g_ref[...].astype(jnp.float32)
+    o_ref[...] = (x - gamma * g + scale * xi).astype(o_ref.dtype)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def langevin_update_2d(x, g, seed: jnp.ndarray, gamma, scale, *, interpret=True):
-    """x, g: (R, LANES) float32, R % BLOCK_ROWS == 0; seed: (2,) uint32."""
-    R, L = x.shape
-    assert L == LANES and R % BLOCK_ROWS == 0, (R, L)
-    grid = (R // BLOCK_ROWS,)
+def _call(x, g, seed, coef, *, interpret: bool):
+    R, C = x.shape
+    br, bc = min(R, BLOCK_ROWS), min(C, LANES)
+    block = pl.BlockSpec((br, bc), lambda i, j, *_: (i, j))
     return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # seed (scalar prefetch-ish)
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, L), x.dtype),
+        partial(_kernel, width=C),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(pl.cdiv(R, br), pl.cdiv(C, bc)),
+            in_specs=[block, block], out_specs=block),
+        out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
         # the update overwrites x block-for-block: alias it so XLA reuses
-        # the buffer instead of double-buffering R*L fp32 through HBM
-        input_output_aliases={0: 0},
+        # the buffer instead of holding a second copy of the leaf
+        input_output_aliases={2: 0},
         interpret=interpret,
-    )(x, g, seed, jnp.asarray(gamma, jnp.float32).reshape(1),
-      jnp.asarray(scale, jnp.float32).reshape(1))
+    )(seed, coef, x, g)
+
+
+@jax.jit
+def langevin_update_2d(x, g, seed: jnp.ndarray, gamma, scale):
+    """x, g: (R, C) of one float dtype, R*C <= 2^32; seed: (2,) uint32.
+
+    Returns x - gamma*g + scale*xi in x's dtype, xi[r, c] the standard
+    normal of counter ``r*C + c`` under ``seed``.
+    """
+    R, C = x.shape
+    if g.shape != x.shape or R * C > 1 << 32:
+        raise ValueError(f"bad shapes x {x.shape}, g {g.shape}")
+    seed = jax.lax.bitcast_convert_type(jnp.asarray(seed, jnp.uint32),
+                                        jnp.int32)
+    coef = jnp.stack([jnp.asarray(gamma, jnp.float32),
+                      jnp.asarray(scale, jnp.float32)])
+    return on_backend(_call, x, g.astype(x.dtype), seed, coef)

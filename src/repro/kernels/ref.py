@@ -7,17 +7,27 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_step import (
+    BLOCK_KV,
+    attend_block,
+    finish,
+    init_carry,
+    kv_block,
+)
 from repro.kernels.rng import normal_from_counter
 
 
 def langevin_update_ref(x: jnp.ndarray, g: jnp.ndarray, seed: jnp.ndarray,
                         gamma, scale) -> jnp.ndarray:
-    """x, g: (R, L) float32; seed (2,) uint32 — same counter scheme as the
-    kernel (row-major global element index)."""
+    """x, g: (R, L); seed (2,) uint32 — same counter scheme as the kernel
+    (row-major global element index), fp32 arithmetic, one rounding to
+    x's dtype."""
     R, L = x.shape
     counter = jnp.arange(R * L, dtype=jnp.uint32).reshape(R, L)
     xi = normal_from_counter(seed[0], seed[1], counter)
-    return x - jnp.float32(gamma) * g + jnp.float32(scale) * xi
+    out = (x.astype(jnp.float32) - jnp.float32(gamma) * g.astype(jnp.float32)
+           + jnp.float32(scale) * xi)
+    return out.astype(x.dtype)
 
 
 def delay_gather_ref(history: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
@@ -26,31 +36,38 @@ def delay_gather_ref(history: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
 
 
 def decode_step_ref(q, k_new, v_new, k_cache, v_cache, valid, slot):
-    """Oracle for the fused decode step — the same slot select, fp32
-    softmax, and einsum orders as the kernel body, batched over rows.
+    """Oracle for the fused decode step — the same slot select, KV blocks,
+    online softmax and op order as the kernel body, one row at a time.
 
     q: (B, KV, G, hd); k_new/v_new: (B, KV, hd); caches: (B, smax, KV, hd);
     valid: (smax,) int32; slot: scalar int32.
     """
-    smax, _, hd = k_cache.shape[1:]
-    scale = 1.0 / math.sqrt(hd)
+    B, KV, G, hd = q.shape
+    smax = k_cache.shape[1]
+    bs = kv_block(smax)
     sel = jax.lax.broadcasted_iota(jnp.int32, k_cache.shape[1:], 0) == slot
-    k = jnp.where(sel[None], k_new[:, None], k_cache)
-    v = jnp.where(sel[None], v_new[:, None], v_cache)
-    q32 = q.astype(jnp.float32) * scale
-    s = jnp.einsum("bngh,bcnh->bngc", q32, k.astype(jnp.float32))
-    s = jnp.where(valid[None, None, None, :] == 1, s, -1e30)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("bngc,bcnh->bngh", p, v.astype(jnp.float32))
-    return o.astype(q.dtype), k, v
+    k = jnp.where(sel[None], k_new[:, None].astype(k_cache.dtype), k_cache)
+    v = jnp.where(sel[None], v_new[:, None].astype(v_cache.dtype), v_cache)
+    rows = jnp.repeat(jnp.asarray(valid, jnp.int32), KV)[None] != 0
+    outs = []
+    for b in range(B):
+        q32 = q[b].reshape(KV * G, hd).astype(jnp.float32) * (
+            1.0 / math.sqrt(hd))
+        carry = init_carry(KV * G, hd)
+        for j in range(smax // bs):
+            blk = slice(j * bs, (j + 1) * bs)
+            carry = attend_block(q32, k[b, blk], v[b, blk],
+                                 rows[:, j * bs * KV:(j + 1) * bs * KV], carry)
+        outs.append(finish(carry, q.dtype))
+    return jnp.stack(outs).reshape(q.shape), k, v
 
 
 def paged_decode_step_ref(q, k_new, v_new, k_pages, v_pages, tables, pos):
     """Oracle for the fused *paged* decode step — the same logical-order
-    page gather, new-row overlay, fp32 softmax, and einsum orders as the
-    kernel body, batched over slots.
+    page blocks (clamped table reads), new-row overlay, online softmax and
+    op order as the kernel body, one slot at a time.  It walks every block
+    of the table; blocks past a slot's position are fully masked, which
+    leaves the softmax state bit-for-bit unchanged.
 
     q: (S, KV, G, hd); k_new/v_new: (S, KV, hd); k_pages/v_pages:
     (n_pages, page_size, KV, hd) shared pool; tables: (S, maxp) int32;
@@ -59,27 +76,31 @@ def paged_decode_step_ref(q, k_new, v_new, k_pages, v_pages, tables, pos):
     S, KV, G, hd = q.shape
     ps = k_pages.shape[1]
     maxp = tables.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    ppb = max(1, min(maxp, BLOCK_KV // ps))
+    width = ppb * ps
+    k_new = k_new.astype(k_pages.dtype)
+    v_new = v_new.astype(v_pages.dtype)
+    t = jnp.arange(width)
+    lane = jnp.arange(width * KV)[None] // KV
+    outs = []
+    for s in range(S):
+        q32 = q[s].reshape(KV * G, hd).astype(jnp.float32) * (
+            1.0 / math.sqrt(hd))
+        carry = init_carry(KV * G, hd)
+        for blk in range(-(-maxp // ppb)):
+            idx = jnp.minimum(blk * ppb + jnp.arange(ppb), maxp - 1)
+            pages = tables[s, idx]
+            base = blk * width
+            new = (t + base == pos[s])[:, None, None]
+            k = jnp.where(new, k_new[s][None],
+                          k_pages[pages].reshape(width, KV, hd))
+            v = jnp.where(new, v_new[s][None],
+                          v_pages[pages].reshape(width, KV, hd))
+            carry = attend_block(q32, k, v, lane + base <= pos[s], carry)
+        outs.append(finish(carry, q.dtype))
     kf = k_pages.reshape(-1, KV, hd)
     vf = v_pages.reshape(-1, KV, hd)
-    # gather each slot's logical window from the pre-store pool, then overlay
-    # the new row at its logical position (matching the kernel's ordering-
-    # insensitive select)
-    gidx = ((tables * ps)[:, :, None]
-            + jnp.arange(ps)[None, None]).reshape(S, maxp * ps)
-    sel = (jnp.arange(maxp * ps)[None, :, None, None]
-           == pos[:, None, None, None])
-    k = jnp.where(sel, k_new[:, None], kf[gidx])      # (S, maxp*ps, KV, hd)
-    v = jnp.where(sel, v_new[:, None], vf[gidx])
-    q32 = q.astype(jnp.float32) * scale
-    s = jnp.einsum("bngh,bcnh->bngc", q32, k.astype(jnp.float32))
-    valid = jnp.arange(maxp * ps)[None, :] <= pos[:, None]
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("bngc,bcnh->bngh", p, v.astype(jnp.float32))
     widx = tables[jnp.arange(S), pos // ps] * ps + pos % ps
-    return (o.astype(q.dtype),
+    return (jnp.stack(outs).reshape(q.shape),
             kf.at[widx].set(k_new).reshape(k_pages.shape),
             vf.at[widx].set(v_new).reshape(v_pages.shape))

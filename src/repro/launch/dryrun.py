@@ -40,7 +40,6 @@ from repro.launch.steps import (  # noqa: E402
     make_sgld_train_step,
     param_structs,
 )
-from repro.utils import use_mesh  # noqa: E402
 
 OUTDIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                       "experiments", "dryrun")
@@ -67,7 +66,7 @@ def lower_combo(arch_id: str, shape_name: str, *, multi_pod: bool = False,
     rep = NamedSharding(mesh, P())
     t0 = time.time()
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = make_sgld_train_step(model, shape, mode=mode)
             key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)

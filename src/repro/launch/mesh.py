@@ -11,6 +11,17 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    """A mesh whose axes GSPMD partitions (``AxisType.Auto``): the
+    repository places arrays with ``NamedSharding`` and lets the compiler
+    propagate the rest, which ``jax.make_mesh``'s default explicit axes
+    refuse (a gather from a vocabulary-sharded embedding, a scan over
+    sharded inputs)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices, have {len(devices)} — run under dryrun.py "
             f"(it sets xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
@@ -31,8 +42,17 @@ def make_debug_mesh(data: int = 2, model: int = 2):
     devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[:n])
+    return _auto_mesh((data, model), ("data", "model"), devices[:n])
+
+
+def make_data_mesh(n: int):
+    """1-D ``data`` mesh over the first ``n`` devices: the layout
+    :class:`~repro.cluster.ClusterEngine` and the bank engines shard their
+    chain axis over."""
+    devices = jax.devices()
+    if len(devices) < n:
+        raise RuntimeError(f"need {n} devices, have {len(devices)}")
+    return _auto_mesh((n,), ("data",), devices[:n])
 
 
 def batch_axes_for(mesh, global_batch: int):

@@ -28,6 +28,7 @@ from repro.data import make_batch
 from repro.models.transformer import Model, init_params
 from repro.train.engine import Engine, checkpoint_hook, log_hook
 from repro.train.loop import make_train_step
+from repro.utils import enable_compile_cache
 
 
 def main(argv=None):
@@ -52,6 +53,7 @@ def main(argv=None):
                     help="commit through the Pallas fused Langevin kernel")
     ap.add_argument("--save", default=None, help="checkpoint path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_arch(args.arch)
     shape = ShapeConfig("cli", seq_len=args.seq, global_batch=args.batch,
@@ -67,8 +69,7 @@ def main(argv=None):
     sgld_cfg = SGLDConfig(mode=args.mode, gamma=args.gamma, sigma=args.sigma,
                           tau=args.tau if args.mode in ("consistent",
                                                         "inconsistent") else 0)
-    sampler, _ = make_train_step(model, sgld_cfg, fused=args.fused,
-                                 interpret=jax.default_backend() != "tpu")
+    sampler, _ = make_train_step(model, sgld_cfg, fused=args.fused)
     key, init_key = jax.random.split(key)
     state = sampler.init(params, init_key)
 

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import activation, dense_init
-from repro.utils import SHARD_MAP_CHECK_KW as _CHECK_KW
-from repro.utils import shard_map
 
 CAPACITY_FACTOR = 1.25
 
@@ -135,8 +133,8 @@ def apply_moe(params, x, cfg, mesh=None, batch_axes=("data",),
         if name in params:
             pspecs[name] = sp
 
-    @partial(shard_map, mesh=mesh, in_specs=(pspecs, bspec),
-             out_specs=(bspec, P()), **_CHECK_KW)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(pspecs, bspec),
+             out_specs=(bspec, P()), check_vma=False)
     def sharded(prm, xl):
         bl, sl, _ = xl.shape
         xt = xl.reshape(bl * sl, d)

@@ -22,6 +22,21 @@ from repro.models.mlp import apply_mlp
 PyTree = Any
 PredictFn = Callable[[PyTree, Any], jnp.ndarray]
 
+# How far two programs computing the same bf16 model may disagree on a BMA
+# log-probability: sharded against single-device, cached decode against a
+# prefill forward, fused kernel against unfused ops.  The programs order and
+# fuse the bf16 ops differently, so an activation can round the other way,
+# and the head emits its logits in bf16: a logit then lands on a
+# neighbouring bf16 value, a whole ulp away.  The models checked here keep
+# |logit| below LOGIT_BOUND (an rms-normed hidden state times a head of std
+# 1/sqrt(d_model)), where one bf16 ulp is 2^-5; LOGP_ATOL allows three such
+# ulps.  Seen: up to 0.023 on the reduced qwen3 on CPU, 0.056 at qwen3-4b
+# widths on a TPU v5e against a highest-precision forward.  A real fault
+# moves them further: leaving the new token out of the paged kernel's
+# attention moves them by 0.3 to 2.7 on the reduced qwen3.
+LOGIT_BOUND = 8.0
+LOGP_ATOL = 3 * 2.0**-5
+
 
 def bma_logits(per_chain_logits: jnp.ndarray, axis: int = 0) -> jnp.ndarray:
     """Bayesian-model-averaged next-token log-probabilities.
@@ -31,9 +46,7 @@ def bma_logits(per_chain_logits: jnp.ndarray, axis: int = 0) -> jnp.ndarray:
     the chain bank — computed stably in log space.  The single source of
     truth for the decode-time reduction: the sharded
     :class:`~repro.cluster.decode.DecodeEngine` path calls it on the
-    all-gathered logit block, the single-device path on the vmapped output,
-    so the two are bitwise-identical by construction (the serve-module
-    parity contract).
+    all-gathered logit block, the single-device path on the vmapped output.
     """
     C = per_chain_logits.shape[axis]
     logp = jax.nn.log_softmax(per_chain_logits.astype(jnp.float32), axis=-1)

@@ -121,14 +121,12 @@ def init_params(key, cfg) -> PyTree:
 # block application
 # ===========================================================================
 def apply_attn(p, x, cfg, positions, *, window, cache=None, cur_pos=None,
-               mesh=None, batch_axes=("data",), fused=False,
-               fused_interpret=True):
+               mesh=None, batch_axes=("data",), fused=False):
     """cache: dict(k, v, pos) for decode; returns (y, new_kv or kv-for-prefill).
 
     ``fused=True`` (decode only) routes the cached-attention read plus the
     KV-slot write through the Pallas decode-step kernel instead of the
-    ``dynamic_update`` + ``decode_attention`` pair; ``fused_interpret``
-    picks the kernel's interpret mode (True everywhere but TPU).
+    ``dynamic_update`` + ``decode_attention`` pair.
     """
     B, S, d = x.shape
     q = x @ p["wq"]
@@ -179,7 +177,7 @@ def apply_attn(p, x, cfg, positions, *, window, cache=None, cur_pos=None,
                 valid &= pos_arr > (cur_pos - window)
             o, k_cache, v_cache = fused_decode_step(
                 q[:, 0], k[:, 0], v[:, 0], cache["k"], cache["v"],
-                valid.astype(jnp.int32), slot, interpret=fused_interpret)
+                valid.astype(jnp.int32), slot)
             o = o[:, None]
         else:
             k_cache = jax.lax.dynamic_update_index_in_dim(cache["k"], k[:, 0],
@@ -193,8 +191,7 @@ def apply_attn(p, x, cfg, positions, *, window, cache=None, cur_pos=None,
     return y, new_kv
 
 
-def apply_paged_attn(p, x, cfg, pages, tables, positions, *, fused=False,
-                     fused_interpret=True):
+def apply_paged_attn(p, x, cfg, pages, tables, positions, *, fused=False):
     """Cached attention over a paged KV pool — one slot per row.
 
     x: (S, 1, d); pages: dict(k, v) of (n_pages, page_size, KV, hd) pools
@@ -221,7 +218,7 @@ def apply_paged_attn(p, x, cfg, pages, tables, positions, *, fused=False,
 
         o, k_pool, v_pool = fused_paged_decode_step(
             q[:, 0], k[:, 0], v[:, 0], pages["k"], pages["v"], tables,
-            positions, interpret=fused_interpret)
+            positions)
         o = o[:, None]
     else:
         widx = (tables[jnp.arange(S), positions // ps] * ps + positions % ps)
@@ -236,7 +233,7 @@ def apply_paged_attn(p, x, cfg, pages, tables, positions, *, fused=False,
 
 def apply_paged_block(p, x, cfg, block: str, pages, tables, positions, *,
                       mesh=None, batch_axes=("data",), fsdp_axes=("data",),
-                      fused=False, fused_interpret=True):
+                      fused=False):
     """One decode step of an attention block against the paged pool — the
     same residual/norm/MLP ops as :func:`apply_block`'s decode path with
     :func:`apply_paged_attn` in place of the ring-cache attention.  Returns
@@ -246,8 +243,7 @@ def apply_paged_block(p, x, cfg, block: str, pages, tables, positions, *,
     rs = cfg.residual_scale
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     attn_out, new_pages = apply_paged_attn(
-        p["attn"], h, cfg, pages, tables, positions, fused=fused,
-        fused_interpret=fused_interpret)
+        p["attn"], h, cfg, pages, tables, positions, fused=fused)
     x = x + rs * attn_out
     h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
     if block == "attn_moe":
@@ -260,8 +256,7 @@ def apply_paged_block(p, x, cfg, block: str, pages, tables, positions, *,
 
 
 def apply_block(p, x, cfg, block: str, positions, *, mesh=None, batch_axes=("data",),
-                fsdp_axes=("data",), cache=None, cur_pos=None, fused=False,
-                fused_interpret=True):
+                fsdp_axes=("data",), cache=None, cur_pos=None, fused=False):
     """Returns (x, aux_loss, new_cache)."""
     rs = cfg.residual_scale
     aux = jnp.float32(0.0)
@@ -273,8 +268,7 @@ def apply_block(p, x, cfg, block: str, positions, *, mesh=None, batch_axes=("dat
         attn_out, kv = apply_attn(p["attn"], h, cfg, positions, window=window,
                                   cache=None if cache is None else cache["attn"],
                                   cur_pos=cur_pos, mesh=mesh,
-                                  batch_axes=batch_axes, fused=fused,
-                                  fused_interpret=fused_interpret)
+                                  batch_axes=batch_axes, fused=fused)
         if block == "hymba_mlp":
             if cache is None:
                 ssm_out = ssm_lib.apply_ssm(p["ssm"], h, cfg)
@@ -333,20 +327,15 @@ class Model:
 
     def __init__(self, cfg, mesh=None, batch_axes=("data",),
                  fsdp_axes=("data",), remat: bool = True,
-                 decode_fused: bool = False, decode_interpret=None):
+                 decode_fused: bool = False):
         self.cfg = cfg
         self.mesh = mesh
         self.batch_axes = tuple(batch_axes)
         self.fsdp_axes = tuple(fsdp_axes)
         self.remat = remat
         # opt-in Pallas fused decode step (cached-attention read + KV slot
-        # write in one kernel); the unfused path is the parity reference.
-        # interpret mode follows the repo's kernel convention: compiled on
-        # TPU, interpreted everywhere else, overridable per Model
+        # write in one kernel); the unfused path is the parity reference
         self.decode_fused = decode_fused
-        self.decode_interpret = (jax.default_backend() != "tpu"
-                                 if decode_interpret is None
-                                 else decode_interpret)
 
     # -- embedding ------------------------------------------------------------
     def embed(self, params, batch) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -563,8 +552,7 @@ class Model:
             x, new_pg = apply_paged_block(
                 layer_p, x, cfg, block, pg, tables, positions,
                 mesh=self.mesh, batch_axes=self.batch_axes,
-                fsdp_axes=self.fsdp_axes, fused=self.decode_fused,
-                fused_interpret=self.decode_interpret)
+                fsdp_axes=self.fsdp_axes, fused=self.decode_fused)
             return x, new_pg
 
         x, new_pages = jax.lax.scan(scan_body, x, (params["stack"], pages))
@@ -618,8 +606,7 @@ class Model:
             return apply_block(p, x, cfg, block, positions, mesh=self.mesh,
                                batch_axes=self.batch_axes,
                                fsdp_axes=self.fsdp_axes, cache=c,
-                               cur_pos=cur_pos, fused=self.decode_fused,
-                               fused_interpret=self.decode_interpret)
+                               cur_pos=cur_pos, fused=self.decode_fused)
 
         if "stack" in params:
             block = cfg.block_pattern[0]

@@ -76,7 +76,6 @@ class PerCoordinateDelay:
 
     tau: int
     fused: bool = False
-    interpret: bool = True
 
     def read(self, ctx: StepContext, ring: RingBuffer) -> PyTree:
         """Per-coordinate read: sample each coordinate's staleness in
@@ -85,5 +84,5 @@ class PerCoordinateDelay:
         delays = sample_coordinate_delays(ctx.key_delay, ring, ctx.delay)
         if self.fused:
             return fused_delay_gather(ring.history, delays, ring.head,
-                                      ring.depth, interpret=self.interpret)
+                                      ring.depth)
         return read_inconsistent(ring, delays)

@@ -48,7 +48,7 @@ MODES = ("sync", "consistent", "inconsistent", "pipeline")
 
 
 def _front_parts(mode: str, *, tau: int, delay_policy: DelayPolicy | None,
-                 fused: bool, interpret: bool) -> list[SamplerTransform]:
+                 fused: bool) -> list[SamplerTransform]:
     """The read-model head shared by every preset: validates ``mode`` /
     ``tau`` and returns the (possibly empty) ``delay_read`` stage."""
     if mode not in MODES:
@@ -59,8 +59,7 @@ def _front_parts(mode: str, *, tau: int, delay_policy: DelayPolicy | None,
     parts: list[SamplerTransform] = []
     if mode in ("consistent", "inconsistent"):
         if delay_policy is None:
-            delay_policy = (PerCoordinateDelay(tau, fused=fused,
-                                               interpret=interpret)
+            delay_policy = (PerCoordinateDelay(tau, fused=fused)
                             if mode == "inconsistent" else TraceDelay(tau))
         parts.append(delay_read(delay_policy))
     return parts
@@ -77,7 +76,7 @@ def _stale_parts(stale_strength: float | None,
 
 def sgld(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
          tau: int = 0, has_aux: bool = False, delay_policy: DelayPolicy | None = None,
-         fused: bool = False, interpret: bool = True,
+         fused: bool = False,
          noise_dtype=jnp.float32, base_batch: int | None = None,
          stale_strength: float | None = None,
          stale_gamma_scale: float = 0.0) -> Sampler:
@@ -90,7 +89,8 @@ def sgld(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
                        whose all-reduce overlaps the next step's compute.
 
     ``fused=True`` commits through the Pallas fused kernel (noise generated
-    in VMEM); ``delay_policy`` overrides the mode's default policy.
+    in VMEM; compiled on a TPU, interpreted elsewhere);
+    ``delay_policy`` overrides the mode's default policy.
 
     ``base_batch`` switches the chain to the heterogeneous-minibatch
     contract: ``grad_fn(params, example)`` becomes a *per-example* oracle
@@ -104,7 +104,7 @@ def sgld(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
     gradient stage (a bitwise no-op on commits with staleness 0).
     """
     parts = _front_parts(mode, tau=tau, delay_policy=delay_policy,
-                         fused=fused, interpret=interpret)
+                         fused=fused)
     if base_batch is None:
         parts.append(gradients(grad_fn, has_aux=has_aux))
     else:
@@ -114,7 +114,7 @@ def sgld(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
     if mode == "pipeline":
         parts.append(pipeline_overlap())
     if fused:
-        parts.append(fused_update(sigma, interpret=interpret))
+        parts.append(fused_update(sigma))
     else:
         parts.append(langevin_noise(sigma, noise_dtype=noise_dtype))
         parts.append(apply_sgld_update())
@@ -124,7 +124,7 @@ def sgld(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
 def svrg(mode: str, grad_fn: GradFn, full_grad_fn: Callable[[Any], Any], *,
          anchor_every: int = 64, gamma=1e-2, sigma: float = 1.0,
          tau: int = 0, has_aux: bool = False,
-         delay_policy: DelayPolicy | None = None, interpret: bool = True,
+         delay_policy: DelayPolicy | None = None,
          noise_dtype=jnp.float32, base_batch: int | None = None,
          stale_strength: float | None = None,
          stale_gamma_scale: float = 0.0) -> Sampler:
@@ -140,7 +140,7 @@ def svrg(mode: str, grad_fn: GradFn, full_grad_fn: Callable[[Any], Any], *,
     Chen-et-al. correction after the variance-reduced oracle.
     """
     parts = _front_parts(mode, tau=tau, delay_policy=delay_policy,
-                         fused=False, interpret=interpret)
+                         fused=False)
     if base_batch is not None:
         parts.append(batch_scaled_gamma(base_batch))
     parts.append(svrg_gradients(grad_fn, full_grad_fn,
@@ -156,8 +156,7 @@ def svrg(mode: str, grad_fn: GradFn, full_grad_fn: Callable[[Any], Any], *,
 def sghmc(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
           friction: float = 1.0, precond: Any = None, tau: int = 0,
           has_aux: bool = False, delay_policy: DelayPolicy | None = None,
-          interpret: bool = True, noise_dtype=jnp.float32,
-          base_batch: int | None = None,
+          noise_dtype=jnp.float32, base_batch: int | None = None,
           stale_strength: float | None = None,
           stale_gamma_scale: float = 0.0) -> Sampler:
     """Stochastic-gradient HMC under any read model: :func:`sgld` with the
@@ -173,7 +172,7 @@ def sghmc(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
     stale-correction machinery composes exactly as in :func:`sgld`.
     """
     parts = _front_parts(mode, tau=tau, delay_policy=delay_policy,
-                         fused=False, interpret=interpret)
+                         fused=False)
     if base_batch is None:
         parts.append(gradients(grad_fn, has_aux=has_aux))
     else:
@@ -188,8 +187,8 @@ def sghmc(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
 
 
 def from_config(cfg, grad_fn: GradFn, has_aux: bool = False, *,
-                fused: bool = False, interpret: bool = True) -> Sampler:
+                fused: bool = False) -> Sampler:
     """Build the preset matching a legacy ``SGLDConfig`` (duck-typed)."""
     return sgld(cfg.mode, grad_fn, gamma=cfg.gamma, sigma=cfg.sigma,
-                tau=cfg.tau, has_aux=has_aux, fused=fused, interpret=interpret,
+                tau=cfg.tau, has_aux=has_aux, fused=fused,
                 noise_dtype=getattr(cfg, "noise_dtype", jnp.float32))

@@ -173,7 +173,7 @@ def apply_sgld_update() -> SamplerTransform:
     return stateless(update)
 
 
-def fused_update(sigma: float, *, interpret: bool = True) -> SamplerTransform:
+def fused_update(sigma: float) -> SamplerTransform:
     """Commit through the Pallas fused kernel: noise is generated *in VMEM*
     (counter-based threefry seeded from this step's noise key) and the
     update is one read of (x, g) + one write of x' — replacing the
@@ -185,7 +185,7 @@ def fused_update(sigma: float, *, interpret: bool = True) -> SamplerTransform:
         scale = jnp.sqrt(2.0 * sigma * ctx.gamma)
         params = fused_langevin_update(ctx.params, ctx.grads,
                                        _key_bits(ctx.key_noise), ctx.gamma,
-                                       scale, interpret=interpret)
+                                       scale)
         return ctx._replace(params=params)
 
     return stateless(update)

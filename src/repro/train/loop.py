@@ -61,11 +61,11 @@ def make_grad_fn(model: Model, num_microbatches: int = 1):
 
 
 def make_train_step(model: Model, sgld_cfg: SGLDConfig, num_microbatches: int = 1,
-                    *, fused: bool = False, interpret: bool = True):
+                    *, fused: bool = False):
     """Returns (sampler, step_fn); step_fn(state, batch, delay) -> (state, metrics)."""
     grad_fn = make_grad_fn(model, num_microbatches)
     sampler = samplers.from_config(sgld_cfg, grad_fn, has_aux=True,
-                                   fused=fused, interpret=interpret)
+                                   fused=fused)
 
     def step_fn(state, batch, delay=0):
         return sampler.step(state, batch, delay)

@@ -3,19 +3,33 @@
 from __future__ import annotations
 
 import math
+import os
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6 spelling
-    shard_map = jax.shard_map
-    SHARD_MAP_CHECK_KW = {"check_vma": False}
-else:  # jax 0.4.x spelling (and the check_vma kwarg was check_rep)
-    from jax.experimental.shard_map import shard_map
-    SHARD_MAP_CHECK_KW = {"check_rep": False}
-
 PyTree = Any
+
+#: where :func:`enable_compile_cache` keeps compiled programs when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed directory of the checkout
+#: (listed in ``.gitignore``), so every run from it finds the same cache
+COMPILE_CACHE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, names the directory (JAX reads
+    it itself, and no other path is set here); otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Entry points call this; importing the
+    library never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_keys(key: jax.Array, tree: PyTree) -> PyTree:
@@ -76,15 +90,6 @@ def tree_ravel(a: PyTree) -> jax.Array:
     """Flatten a pytree into a single 1-D vector (float32)."""
     leaves = jax.tree_util.tree_leaves(a)
     return jnp.concatenate([jnp.ravel(x).astype(jnp.float32) for x in leaves])
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` across JAX versions:
-    ``jax.set_mesh`` where it exists (>= 0.6), the ``Mesh`` context itself
-    on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
 
 
 def bucket_size(n: int, buckets=None) -> int:

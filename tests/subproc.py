@@ -7,6 +7,10 @@ every child in its own process group and, when the watchdog fires,
 SIGKILLs the *group* — grandchildren holding the stdout/stderr pipes can't
 keep ``communicate()`` blocked — then fails the test with the captured
 output tails instead of hanging.
+
+For CPU tests only: the children run with ``JAX_PLATFORMS=cpu``.  A chip
+belongs to one process, so nothing that drives a TPU starts children after
+touching JAX.
 """
 
 import json

@@ -12,11 +12,9 @@ from repro.kernels.ops import (
     delay_gather_flat,
     fused_delay_gather,
     fused_langevin_update,
-    langevin_update_flat,
 )
 from repro.kernels.ref import delay_gather_ref, langevin_update_ref
 from repro.kernels.rng import normal_from_counter, threefry2x32
-from repro.utils import round_up
 
 
 # ---------------------------------------------------------------------------
@@ -50,20 +48,16 @@ def test_rng_deterministic_and_seed_sensitive():
 # ---------------------------------------------------------------------------
 # langevin_update kernel
 # ---------------------------------------------------------------------------
-@given(n=st.integers(1, 5_000_00), gamma=st.floats(1e-5, 0.5),
-       scale=st.floats(0.0, 1.0))
+@given(rows=st.integers(1, 600), cols=st.integers(1, 2100),
+       gamma=st.floats(1e-5, 0.5), scale=st.floats(0.0, 1.0))
 @settings(max_examples=10, deadline=None)
-def test_langevin_kernel_vs_ref(n, gamma, scale):
-    key = jax.random.PRNGKey(n % 17)
-    x = jax.random.normal(key, (n,))
-    g = jax.random.normal(jax.random.PRNGKey(1), (n,))
-    seed = jnp.array([n % 251, 77], jnp.uint32)
-    got = langevin_update_flat(x, g, seed, gamma, scale)
-    rows = round_up(-(-n // lu.LANES), lu.BLOCK_ROWS)
-    pad = rows * lu.LANES
-    xp = jnp.zeros((pad,)).at[:n].set(x).reshape(rows, lu.LANES)
-    gp = jnp.zeros((pad,)).at[:n].set(g).reshape(rows, lu.LANES)
-    want = langevin_update_ref(xp, gp, seed, gamma, scale).reshape(-1)[:n]
+def test_langevin_kernel_vs_ref(rows, cols, gamma, scale):
+    """Any 2-D view, ragged edge blocks in both dimensions included."""
+    x = jax.random.normal(jax.random.PRNGKey(rows % 17), (rows, cols))
+    g = jax.random.normal(jax.random.PRNGKey(1), (rows, cols))
+    seed = jnp.array([cols % 251, 77], jnp.uint32)
+    got = lu.langevin_update_2d(x, g, seed, gamma, scale)
+    want = langevin_update_ref(x, g, seed, gamma, scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
 
@@ -73,7 +67,8 @@ def test_langevin_kernel_dtypes(dtype):
     n = 3000
     x = jnp.ones((n,), dtype)
     g = jnp.ones((n,), dtype)
-    out = langevin_update_flat(x, g, jnp.array([0, 0], jnp.uint32), 0.5, 0.0)
+    out = lu.langevin_update_2d(x[None], g[None], jnp.array([0, 0], jnp.uint32),
+                                0.5, 0.0)
     np.testing.assert_allclose(np.asarray(out, np.float32), 0.5, rtol=1e-2)
     assert out.dtype == dtype
 

@@ -22,7 +22,8 @@ from repro.launch.steps import (build_model, param_structs, batch_specs,
 from repro.launch import roofline as rl
 from repro.launch.jaxpr_cost import step_cost
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(data=2, model=4)
 shape = ShapeConfig("ci", seq_len=64, global_batch=4, kind="train",
                     num_microbatches=2)
 cfg0 = replace(get_reduced("qwen3-4b"), num_heads=8, num_kv_heads=2)
@@ -31,8 +32,7 @@ pstructs, pshard = param_structs(cfg, mesh, faxes)
 bstructs = batch_specs(cfg, shape, mesh, baxes)
 rep = NamedSharding(mesh, P())
 out = {}
-from repro.utils import use_mesh
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     step = make_sgld_train_step(model, shape)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
     compiled = jax.jit(step, out_shardings=(pshard, rep)).lower(

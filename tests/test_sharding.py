@@ -18,9 +18,9 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_reduced
 from repro.models import moe as moe_mod
 from repro.models.moe import apply_moe, init_moe
-from repro.utils import use_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(data=2, model=4)
 cfg = replace(get_reduced("phi3.5-moe-42b-a6.6b"), dtype="float32",
               num_experts=8, experts_per_token=2)
 p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
@@ -31,7 +31,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
 # headroom that admits every routed token.
 moe_mod.CAPACITY_FACTOR = 1e9
 y_local, aux_local = apply_moe(p, x, cfg, mesh=None)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     y_shard, aux_shard = jax.jit(
         lambda p, x: apply_moe(p, x, cfg, mesh=mesh, batch_axes=("data",)))(p, x)
 err = float(jnp.abs(y_local - y_shard).max())
@@ -39,7 +39,7 @@ rel = err / float(jnp.abs(y_local).max())
 
 # production capacity factor: path must still run and stay finite
 moe_mod.CAPACITY_FACTOR = 1.25
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     y_drop, _ = jax.jit(
         lambda p, x: apply_moe(p, x, cfg, mesh=mesh, batch_axes=("data",)))(p, x)
 print(json.dumps({"rel_err": rel,
@@ -60,7 +60,8 @@ from repro.models.common import partition_tree
 from repro.models.transformer import Model, init_params
 from repro.launch.steps import make_sgld_train_step, sanitized_named
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(data=2, model=4)
 cfg = replace(get_reduced("qwen3-4b"), dtype="float32")
 shape = ShapeConfig("t", seq_len=64, global_batch=4, kind="train",
                     num_microbatches=2)
@@ -72,8 +73,7 @@ pshard = sanitized_named(mesh, specs, params)
 params = jax.device_put(params, pshard)
 batch = make_batch(cfg, shape, jax.random.PRNGKey(1), "train")
 step = make_sgld_train_step(model, shape, mode="sync", gamma=1e-3, sigma=1e-8)
-from repro.utils import use_mesh
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     jstep = jax.jit(step, out_shardings=(pshard, NamedSharding(mesh, P())))
     new_params, loss = jstep(params, batch, jnp.array([0, 1], jnp.uint32))
     loss2 = None
