@@ -106,11 +106,13 @@ def make_batches(cfg, seed: int, chains: int):
         keys)
 
 
-def chunk_shapes(cfg, engine, chains: int):
+def chunk_shapes(cfg, engine, chains: int, mesh=None):
     """Abstract ``(state, batches, extra)`` of one sampling chunk, for
-    :meth:`ClusterEngine.lower_chunk`."""
+    :meth:`ClusterEngine.lower_chunk`; with ``mesh``, placed the way the
+    sharded run places them (chain axis over ``data``)."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.models.transformer import init_params
 
@@ -122,7 +124,15 @@ def chunk_shapes(cfg, engine, chains: int):
         lambda b: jax.ShapeDtypeStruct((COMMITS,) + b.shape[1:], b.dtype),
         batches)
     extra = {"rv": jax.ShapeDtypeStruct((COMMITS, chains), jnp.int32)}
-    return state, batches, extra
+    if mesh is None:
+        return state, batches, extra
+
+    def place(tree, spec):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    return (place(state, P("data")), place(batches, P(None, "data")),
+            place(extra, P(None, "data")))
 
 
 def async_schedules(chains: int, seed: int):
@@ -366,6 +376,9 @@ def four_chips(cfg, seed: int):
     chains = 4
     mesh = make_data_mesh(chains)
     model, engine = sampling_engine(cfg, chains, mesh)
+    require_kernel(engine.lower_chunk(
+        *chunk_shapes(cfg, engine, chains, mesh)).compile(),
+        "sharded sampling chunk")
     host0, keys, state, losses, scheds, batches = run_sampling(engine, cfg,
                                                                seed)
     placed = chain_devices(state.params)

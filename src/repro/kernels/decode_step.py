@@ -110,8 +110,7 @@ def _chain_batched(chains, n_shared: int = 0):
     The pallas batching rule would add the chain axis to the grid, but a
     TPU kernel cannot take a batched block of an HBM (``pl.ANY``) operand.
     Under ``vmap`` unbatched operands are broadcast along the chain axis;
-    batched shared operands (per-chain page tables) fall back to one call
-    per chain.
+    the shared operands (page tables, positions) must stay unbatched.
     """
 
     @jax.custom_batching.custom_vmap
@@ -122,15 +121,12 @@ def _chain_batched(chains, n_shared: int = 0):
     @one.def_vmap
     def _rule(axis_size, in_batched, *args):
         if any(in_batched[:n_shared]):
-            def per_chain(xs):
-                return one(*xs)
-            full = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
-                    for a, b in zip(args, in_batched)]
-            out = jax.lax.map(per_chain, full)
-        else:
-            rest = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
-                    for a, b in zip(args[n_shared:], in_batched[n_shared:])]
-            out = chains(*args[:n_shared], *rest)
+            raise ValueError(
+                f"the first {n_shared} operands are shared by every chain and "
+                "cannot carry the vmapped axis")
+        rest = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args[n_shared:], in_batched[n_shared:])]
+        out = chains(*args[:n_shared], *rest)
         return tuple(out), (True,) * len(out)
 
     return one

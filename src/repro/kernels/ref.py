@@ -1,4 +1,11 @@
-"""Pure-jnp oracles for the Pallas kernels (bit-identical math)."""
+"""Pure-jnp oracles for the Pallas kernels.
+
+``decode_step_ref`` and ``paged_decode_step_ref`` walk the kernels' KV
+blocks with the kernels' own online-softmax helpers, so they match them bit
+for bit and pin the blocking and the DMA plumbing.  ``*_dense_ref`` attend
+with one full softmax over the whole cache and share no code with the
+kernels, so they pin the attention math itself (to fp32 rounding).
+"""
 
 from __future__ import annotations
 
@@ -60,6 +67,48 @@ def decode_step_ref(q, k_new, v_new, k_cache, v_cache, valid, slot):
                                  rows[:, j * bs * KV:(j + 1) * bs * KV], carry)
         outs.append(finish(carry, q.dtype))
     return jnp.stack(outs).reshape(q.shape), k, v
+
+
+def _dense_attend(q, k, v, keep):
+    """q: (B, KV, G, hd); k, v: (B, T, KV, hd); keep: (B, T) bool.  One
+    fp32 softmax over all T rows per head, normalized before the value
+    sum."""
+    q32 = q.astype(jnp.float32) / math.sqrt(q.shape[-1])
+    s = jnp.einsum("bngh,bcnh->bngc", q32, k.astype(jnp.float32))
+    s = jnp.where(keep[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bngc,bcnh->bngh", p, v.astype(jnp.float32))
+    return o.astype(q.dtype)
+
+
+def decode_step_dense_ref(q, k_new, v_new, k_cache, v_cache, valid, slot):
+    """:func:`decode_step_ref`'s contract, attended with one dense softmax
+    (independent of the kernel's code)."""
+    k = k_cache.at[:, slot].set(k_new.astype(k_cache.dtype))
+    v = v_cache.at[:, slot].set(v_new.astype(v_cache.dtype))
+    keep = jnp.broadcast_to(jnp.asarray(valid) != 0, k.shape[:2])
+    return _dense_attend(q, k, v, keep), k, v
+
+
+def paged_decode_step_dense_ref(q, k_new, v_new, k_pages, v_pages, tables,
+                                pos):
+    """:func:`paged_decode_step_ref`'s contract: write each slot's new row
+    into its page, gather the slot's pages in logical order and attend to
+    logical rows ``<= pos`` with one dense softmax (independent of the
+    kernel's code)."""
+    S, KV, G, hd = q.shape
+    ps = k_pages.shape[1]
+    maxp = tables.shape[1]
+    kf = k_pages.reshape(-1, KV, hd)
+    vf = v_pages.reshape(-1, KV, hd)
+    widx = tables[jnp.arange(S), pos // ps] * ps + pos % ps
+    kf = kf.at[widx].set(k_new.astype(kf.dtype))
+    vf = vf.at[widx].set(v_new.astype(vf.dtype))
+    gidx = ((tables * ps)[:, :, None]
+            + jnp.arange(ps)[None, None]).reshape(S, maxp * ps)
+    keep = jnp.arange(maxp * ps)[None] <= pos[:, None]
+    return (_dense_attend(q, kf[gidx], vf[gidx], keep),
+            kf.reshape(k_pages.shape), vf.reshape(v_pages.shape))
 
 
 def paged_decode_step_ref(q, k_new, v_new, k_pages, v_pages, tables, pos):

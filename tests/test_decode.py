@@ -228,12 +228,8 @@ def test_fused_kernel_chain_batched_bitwise():
                                   np.asarray(want, jnp.float32)), c
 
 
-def test_fused_decode_matches_unfused(cfg, model, bank):
-    """fused=True is an opt-in hot-path swap: same tokens, BMA logits within
-    ``LOGP_ATOL`` of the unfused engine.  The kernel's online softmax
-    normalizes after the value sum and reduces all heads in one matmul, a
-    different fp32 order from the unfused einsums."""
-    prompt = prompt_batch(3, 5, cfg.vocab_size, seed=2)
+def fused_vs_unfused(model, bank, vocab):
+    prompt = prompt_batch(3, 5, vocab, seed=2)
     plain = DecodeEngine(model=model, params=bank, max_seq=32,
                          return_logits=True)
     fused = DecodeEngine(model=model, params=bank, max_seq=32, fused=True,
@@ -241,7 +237,30 @@ def test_fused_decode_matches_unfused(cfg, model, bank):
     a = plain.generate(prompt, 6)
     b = fused.generate(prompt, 6)
     assert np.array_equal(a.tokens, b.tokens)
-    np.testing.assert_allclose(a.logits, b.logits, rtol=0, atol=LOGP_ATOL)
+    return a.logits, b.logits
+
+
+def test_fused_decode_matches_unfused(cfg, model, bank):
+    """fused=True is an opt-in hot-path swap: same tokens, BMA logits within
+    one bf16 logit ulp (2^-5 below ``LOGIT_BOUND``) of the unfused engine.
+    The kernel's online softmax normalizes after the value sum, another
+    fp32 order than the unfused softmax, so a bf16 attention output can
+    round to its neighbour: on CPU the logits agree bitwise for three
+    steps and then differ by 0.0132, 2.4 times inside the bound."""
+    a, b = fused_vs_unfused(model, bank, cfg.vocab_size)
+    np.testing.assert_allclose(a, b, rtol=0, atol=2.0**-5)
+
+
+def test_fused_decode_matches_unfused_float32(cfg):
+    """In float32 no rounding to bf16 hides the kernel's attention math:
+    the fused engine's BMA logits stay within 1e-5 of the unfused one's
+    (2.4e-6 seen on CPU)."""
+    cfg32 = replace(cfg, dtype="float32")
+    bank32 = jax.vmap(lambda k: init_params(k, cfg32))(
+        jax.random.split(jax.random.PRNGKey(0), C))
+    a, b = fused_vs_unfused(Model(cfg32, remat=False), bank32,
+                            cfg.vocab_size)
+    np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

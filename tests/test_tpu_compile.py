@@ -1,7 +1,7 @@
 """Compiles for a described TPU v5e chip, with no chip attached.
 
 The Pallas kernels of the main path at qwen3-4b widths, and the sampling
-chunk ``chip_smoke.py`` runs, go through the chip's own compiler: what it
+chunks ``chip_smoke.py`` runs on one chip and on four, go through the chip's own compiler: what it
 refuses (an untiled block, a scalar outside SMEM, too much VMEM, a program
 larger than the chip's memory) fails here.  Each compiled program must
 hold the kernel as a ``tpu_custom_call``, not the Pallas interpreter.
@@ -113,20 +113,42 @@ def test_paged_decode_step_compiles_chain_batched(one_chip):
              *args)
 
 
-def test_smoke_sampling_chunk_fits_one_chip(one_chip):
-    """The chunk ``chip_smoke.py`` runs (qwen3-4b widths, its depth, chains
-    and tau) compiles for one chip with the fused update as a kernel, and
-    its arguments, outputs and temporaries fit the chip's 16 GiB."""
+@pytest.fixture(scope="module")
+def smoke():
     spec = importlib.util.spec_from_file_location(
         "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
-    smoke = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(smoke)
-    cfg = smoke.smoke_config()
-    _, engine = smoke.sampling_engine(cfg, 1)
-    args = _on(one_chip, smoke.chunk_shapes(cfg, engine, 1))
-    compiled = engine.lower_chunk(*args).compile()
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _require_fits(compiled):
     assert "tpu_custom_call" in compiled.as_text()
     ma = compiled.memory_analysis()
     need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert need < 16 * 2**30, ma
+
+
+def test_smoke_sampling_chunk_fits_one_chip(one_chip, smoke):
+    """The chunk ``chip_smoke.py`` runs (qwen3-4b widths, its depth, chains
+    and tau) compiles for one chip with the fused update as a kernel, and
+    its arguments, outputs and temporaries fit the chip's 16 GiB."""
+    cfg = smoke.smoke_config()
+    _, engine = smoke.sampling_engine(cfg, 1)
+    args = _on(one_chip, smoke.chunk_shapes(cfg, engine, 1))
+    _require_fits(engine.lower_chunk(*args).compile())
+
+
+def test_smoke_sharded_sampling_chunk_fits_four_chips(topo, smoke):
+    """The ``shard_map`` chunk ``chip_smoke.py --chips 4`` runs, one chain
+    per chip of the 2x2 host, keeps the fused update a kernel, and each
+    chip's share fits its 16 GiB."""
+    from jax.sharding import AxisType
+
+    mesh = jax.make_mesh((4,), ("data",), devices=topo.devices,
+                         axis_types=(AxisType.Auto,))
+    cfg = smoke.smoke_config()
+    _, engine = smoke.sampling_engine(cfg, 4, mesh)
+    _require_fits(engine.lower_chunk(
+        *smoke.chunk_shapes(cfg, engine, 4, mesh)).compile())
