@@ -626,9 +626,14 @@ class ClusterEngine:
         return self._run(carry, steps=steps, start=done, base_steps=base,
                          checkpoint_path=checkpoint_path, **kw)
 
-    def _run(self, state, *, steps, schedule=None, batches=None, key=None,
-             data=None, batch_sizes=None, poison=None, checkpoint_path=None,
-             checkpoint_every=None, start=0, base_steps=None):
+    def _run(self, state, *, steps, **kw):
+        with _span("cluster.run", steps=steps, chains=self.num_chains):
+            return self._drive(state, steps=steps, **kw)
+
+    def _schedule_inputs(self, state, schedule, steps, poison, base_steps):
+        """-> (extra, commit_times, batch_info, base): the run's per-commit
+        scan inputs, with read versions (and worker slots) rebased onto the
+        initial commit counter ``base`` and copied to the device."""
         extra, commit_times, batch_info = self._compile_schedule(schedule,
                                                                  steps)
         staleness = (np.arange(steps, dtype=np.int64)[:, None] - extra["rv"])
@@ -663,7 +668,14 @@ class ClusterEngine:
             # (the carried chain key is deliberately untouched in this mode)
             extra["slot"] = jnp.asarray(
                 extra["slot"] + base[None, :], jnp.int32)
+        return extra, commit_times, batch_info, base
 
+    def _drive(self, state, *, steps, schedule=None, batches=None, key=None,
+               data=None, batch_sizes=None, poison=None, checkpoint_path=None,
+               checkpoint_every=None, start=0, base_steps=None):
+        with _span("cluster.schedule"):
+            extra, commit_times, batch_info, base = self._schedule_inputs(
+                state, schedule, steps, poison, base_steps)
         carry = self._as_carry(state)
         use_health = isinstance(carry, HealthState)
         chunk_post = None

@@ -199,6 +199,7 @@ def _call(q, k_new, v_new, k_cache, v_cache, valid, slot, *,
                    jax.ShapeDtypeStruct(q.shape, q.dtype)],
         input_output_aliases={4: 0, 5: 1},  # caches update in place
         interpret=interpret,
+        name="decode_step",
     )(slot, q, k_new[:, :, None], v_new[:, :, None], k_cache, v_cache, valid)
 
 
@@ -308,6 +309,7 @@ def _paged_call(tables, pos, q, k_new, v_new, k_pages, v_pages, *,
                    jax.ShapeDtypeStruct(q.shape, q.dtype)],
         input_output_aliases={5: 0, 6: 1},  # pools update in place
         interpret=interpret,
+        name="paged_decode_step",
     )(tables, pos, q, k_new[:, :, None], v_new[:, :, None], k_pages, v_pages)
 
 

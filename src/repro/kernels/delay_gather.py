@@ -42,6 +42,7 @@ def _call(history, slots, *, interpret: bool):
         out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), history.dtype),
         interpret=interpret,
+        name="delay_gather",
     )(history, slots)
 
 

@@ -59,6 +59,7 @@ def _call(x, g, seed, coef, *, interpret: bool):
         # the buffer instead of holding a second copy of the leaf
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="langevin_update",
     )(seed, coef, x, g)
 
 

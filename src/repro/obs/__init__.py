@@ -5,8 +5,8 @@ has to be a first-class, exportable quantity — not a benchmark total.  Three
 layers, all host-side by construction (safe on compiled paths):
 
 - :mod:`repro.obs.trace` — a low-overhead span tracer (``span("decode.
-  generate", **attrs)``, engine hooks at chunk boundaries, parent-linked
-  per-thread trees, disabled-by-default null path);
+  generate", **attrs)``, parent-linked per-thread trees, disabled-by-default
+  null path); enabled live spans also land in the ``jax.profiler`` trace;
 - :mod:`repro.obs.metrics` — a process-global registry of counters, gauges,
   and fixed-bucket histograms (per-token latency, per-commit staleness, W2,
   grad evals, bank utilization) with JSON snapshot and Prometheus text
@@ -29,4 +29,4 @@ from repro.obs.timeline import (  # noqa: F401
     to_chrome_trace,
     write_chrome_trace,
 )
-from repro.obs.trace import Span, Tracer, span, trace_hook, tracer  # noqa: F401
+from repro.obs.trace import Span, Tracer, span, tracer  # noqa: F401

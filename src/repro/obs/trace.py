@@ -12,10 +12,20 @@ asserted in ``tests/test_obs.py``).
 
 Cost discipline: the global tracer starts **disabled**, and a disabled
 ``span()`` returns a shared no-op context — two attribute loads and a
-branch, no allocation — so engines leave their span sites on permanently.
-Enabled spans cost one clock read on entry and one on exit plus a list
-append; parents are linked through a per-thread stack, so concurrent
-serving threads get independent span trees over one shared buffer.
+branch, no allocation, no clock read, no profiler call — so engines leave
+their span sites on permanently.  Enabled spans cost one clock read on
+entry and one on exit plus a list append; parents are linked through a
+per-thread stack, so concurrent serving threads get independent span trees
+over one shared buffer.
+
+Profiler bridge: an enabled live span also runs its body inside a
+``jax.profiler.TraceAnnotation(name, **attrs)``, so while ``jax.profiler``
+records, the span lands on the profiler's host plane, on the clock of the
+device planes, with its attributes as event stats.  Spans backfilled with
+:meth:`Tracer.record` (``paged.request``, ``paged.shed``, ``paged.admit``)
+were measured elsewhere and cannot be annotated after the fact: they stay
+in this module's buffer only.  JAX is imported on the first enabled span,
+so host-only readers of the buffer (``scripts/obstool.py``) never import it.
 
 Timestamps are seconds on a process-local monotonic clock
 (``perf_counter`` minus the module-import epoch); the Chrome-trace exporter
@@ -28,10 +38,9 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from typing import Callable, Iterator, Optional
+from typing import Iterator, Optional
 
-__all__ = ["Span", "Tracer", "disable", "enable", "now", "span", "tracer",
-           "trace_hook"]
+__all__ = ["Span", "Tracer", "disable", "enable", "now", "span", "tracer"]
 
 _EPOCH = time.perf_counter()
 
@@ -99,13 +108,17 @@ _NULL_CTX = _NullCtx()
 
 class _SpanCtx:
     """Context manager for one live span (hand-rolled: no generator frame
-    per call on the hot path)."""
+    per call on the hot path).  Its body also runs inside a profiler
+    annotation of the same name and attributes."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
+        from jax.profiler import TraceAnnotation
+
         self._tracer = tracer
         self._span = span
+        self._annotation = TraceAnnotation(span.name, **span.attrs)
 
     def __enter__(self) -> Span:
         stack = self._tracer._stack()
@@ -113,9 +126,11 @@ class _SpanCtx:
             self._span.parent_id = stack[-1].span_id
         stack.append(self._span)
         self._span.t0 = now()
+        self._annotation.__enter__()
         return self._span
 
-    def __exit__(self, *_exc) -> bool:
+    def __exit__(self, *exc) -> bool:
+        self._annotation.__exit__(*exc)
         sp = self._span
         sp.t1 = now()
         self._tracer._stack().pop()
@@ -157,7 +172,8 @@ class Tracer:
         return self
 
     def span(self, name: str, **attrs):
-        """Context manager recording one span around its body."""
+        """Context manager recording one span around its body, and, while
+        ``jax.profiler`` records, one profiler event of the same name."""
         if not self.enabled:
             return _NULL_CTX
         sp = Span(name, next(self._ids), None, 0.0, attrs,
@@ -166,7 +182,9 @@ class Tracer:
 
     def record(self, name: str, t0: float, t1: float, **attrs) -> None:
         """Backfill a completed span from caller-measured timestamps
-        (seconds on the :func:`now` clock).  No-op while disabled."""
+        (seconds on the :func:`now` clock).  No-op while disabled.  The
+        interval is over, so no profiler event can be made for it: a
+        recorded span stays in this buffer."""
         if not self.enabled:
             return
         sp = Span(name, next(self._ids), None, t0, attrs,
@@ -223,28 +241,6 @@ def enable() -> Tracer:
 def disable() -> Tracer:
     """Turn off the process-global tracer; returns it."""
     return _GLOBAL.disable()
-
-
-def trace_hook(name: str = "engine.chunk",
-               to: Optional[Tracer] = None) -> Callable:
-    """An :class:`~repro.train.engine.Engine`-style hook emitting one span
-    per chunk boundary.
-
-    Hooks run between jitted chunks, so each span covers the host interval
-    from the previous boundary (or hook creation) to this one — dispatch,
-    device wait, and sibling hooks included.  Attributes carry the commit
-    range.  This is the sanctioned way to see chunk timing without touching
-    the scan itself.
-    """
-    target = to if to is not None else _GLOBAL
-    prev = [now(), 0]  # [boundary time, step at that boundary]
-
-    def hook(step_end: int, _state, _aux) -> None:
-        t = now()
-        target.record(name, prev[0], t, start=prev[1], end=step_end)
-        prev[0], prev[1] = t, step_end
-
-    return hook
 
 
 def iter_spans(spans) -> Iterator[dict]:

@@ -13,6 +13,12 @@ A :class:`SamplerTransform` is a pure pair of functions threaded by the
 transform whose state is the tuple of member states, exactly like
 ``optax.chain``.  The paper's four read models are one-line chains over
 five primitives (see :mod:`repro.samplers.presets`).
+
+Each primitive carries a stage ``name`` (its factory's name), and ``chain``
+runs a named member's ``update`` under ``jax.named_scope(name)``: the
+compiled program's op metadata then reads ``.../delay_read/...``,
+``.../gradients/...``, ``.../fused_update/...``, so a profile attributes
+device time to stages by name.  A scope is trace-time metadata only.
 """
 
 from __future__ import annotations
@@ -49,14 +55,19 @@ UpdateFn = Callable[[StepContext, Any], tuple[StepContext, Any]]
 
 
 class SamplerTransform(NamedTuple):
-    """An optax-style (init, update) pair over :class:`StepContext`."""
+    """An optax-style (init, update) pair over :class:`StepContext`, with
+    the stage name :func:`chain` scopes its update under (``None``: no
+    scope of its own)."""
 
     init: InitFn
     update: UpdateFn
+    name: Optional[str] = None
 
 
-def stateless(update_ctx: Callable[[StepContext], StepContext]) -> SamplerTransform:
-    """Lift a pure ``ctx -> ctx`` function into a stateless transform."""
+def stateless(update_ctx: Callable[[StepContext], StepContext],
+              name: Optional[str] = None) -> SamplerTransform:
+    """Lift a pure ``ctx -> ctx`` function into a stateless transform
+    named ``name``."""
 
     def init(params):
         del params
@@ -65,11 +76,12 @@ def stateless(update_ctx: Callable[[StepContext], StepContext]) -> SamplerTransf
     def update(ctx, state):
         return update_ctx(ctx), state
 
-    return SamplerTransform(init, update)
+    return SamplerTransform(init, update, name)
 
 
 def chain(*transforms: SamplerTransform) -> SamplerTransform:
-    """Compose transforms left-to-right; state is the tuple of member states."""
+    """Compose transforms left-to-right; state is the tuple of member states.
+    A named member's update runs under ``jax.named_scope`` of its name."""
 
     def init(params):
         return tuple(t.init(params) for t in transforms)
@@ -77,7 +89,11 @@ def chain(*transforms: SamplerTransform) -> SamplerTransform:
     def update(ctx, state):
         new_state = []
         for t, s in zip(transforms, state):
-            ctx, s = t.update(ctx, s)
+            if t.name is None:
+                ctx, s = t.update(ctx, s)
+            else:
+                with jax.named_scope(t.name):
+                    ctx, s = t.update(ctx, s)
             new_state.append(s)
         return ctx, tuple(new_state)
 

@@ -98,7 +98,7 @@ def gradients(grad_fn: GradFn, has_aux: bool = False) -> SamplerTransform:
         grads, aux = out if has_aux else (out, None)
         return ctx._replace(grads=grads, aux=aux)
 
-    return stateless(update)
+    return stateless(update, "gradients")
 
 
 def masked_gradients(grad_fn: GradFn, has_aux: bool = False) -> SamplerTransform:
@@ -123,7 +123,7 @@ def masked_gradients(grad_fn: GradFn, has_aux: bool = False) -> SamplerTransform
         aux = masked_mean(per_aux, mb.size) if has_aux else None
         return ctx._replace(grads=grads, aux=aux)
 
-    return stateless(update)
+    return stateless(update, "masked_gradients")
 
 
 def batch_scaled_gamma(base_batch: int) -> SamplerTransform:
@@ -142,7 +142,7 @@ def batch_scaled_gamma(base_batch: int) -> SamplerTransform:
         scale = mb.size.astype(jnp.float32) / jnp.float32(base_batch)
         return ctx._replace(gamma=ctx.gamma * scale)
 
-    return stateless(update)
+    return stateless(update, "batch_scaled_gamma")
 
 
 def langevin_noise(sigma: float, schedule=None, noise_dtype=jnp.float32) -> SamplerTransform:
@@ -158,7 +158,7 @@ def langevin_noise(sigma: float, schedule=None, noise_dtype=jnp.float32) -> Samp
         return ctx._replace(noise=noise_like(ctx.key_noise, ctx.params, scale,
                                              noise_dtype))
 
-    return stateless(update)
+    return stateless(update, "langevin_noise")
 
 
 def apply_sgld_update() -> SamplerTransform:
@@ -170,7 +170,7 @@ def apply_sgld_update() -> SamplerTransform:
         noise = ctx.noise if ctx.noise is not None else tree_zeros_like(ctx.params)
         return ctx._replace(params=sgld_apply(ctx.params, ctx.grads, ctx.gamma, noise))
 
-    return stateless(update)
+    return stateless(update, "apply_sgld_update")
 
 
 def fused_update(sigma: float) -> SamplerTransform:
@@ -188,7 +188,7 @@ def fused_update(sigma: float) -> SamplerTransform:
                                        scale)
         return ctx._replace(params=params)
 
-    return stateless(update)
+    return stateless(update, "fused_update")
 
 
 def _oracle_grads(grad_fn: GradFn, params: PyTree, batch: Any,
@@ -274,7 +274,7 @@ def svrg_gradients(grad_fn: GradFn, full_grad_fn: Callable[[PyTree], PyTree],
             grads, anchor_grads, state.anchor_grad)
         return ctx._replace(grads=corrected, aux=aux), state
 
-    return SamplerTransform(init, update)
+    return SamplerTransform(init, update, "svrg_gradients")
 
 
 def stale_correction(strength: float = 1.0,
@@ -321,7 +321,7 @@ def stale_correction(strength: float = 1.0,
                                          ctx.delay.astype(jnp.float32), 0.0))
         return ctx._replace(grads=corrected, gamma=gamma)
 
-    return stateless(update)
+    return stateless(update, "stale_correction")
 
 
 def sghmc_update(sigma: float, *, friction: float = 1.0,
@@ -390,7 +390,7 @@ def sghmc_update(sigma: float, *, friction: float = 1.0,
             ctx.params, momentum)
         return ctx._replace(params=params, noise=noise), momentum
 
-    return SamplerTransform(init, update)
+    return SamplerTransform(init, update, "sghmc_update")
 
 
 def pipeline_overlap() -> SamplerTransform:
@@ -406,7 +406,7 @@ def pipeline_overlap() -> SamplerTransform:
             raise ValueError("pipeline_overlap needs a gradients() stage first")
         return ctx._replace(grads=pending), ctx.grads
 
-    return SamplerTransform(init, update)
+    return SamplerTransform(init, update, "pipeline_overlap")
 
 
 def delay_read(policy: DelayPolicy) -> SamplerTransform:
@@ -424,4 +424,4 @@ def delay_read(policy: DelayPolicy) -> SamplerTransform:
         ring = delay_lib.push(ring, ctx.params)
         return ctx._replace(x_hat=policy.read(ctx, ring)), ring
 
-    return SamplerTransform(init, update)
+    return SamplerTransform(init, update, "delay_read")

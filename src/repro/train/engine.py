@@ -163,39 +163,48 @@ def drive_chunks(run_chunk, state: SamplerState, *, steps: int,
     done = 0
     while done < steps:
         n = min(chunk_size, steps - done)
-        # host-side chunk span (null ctx when tracing is disabled): covers
-        # batch slicing, the jitted dispatch, and the hooks — device
-        # execution is async, so hooks that pull values sync inside it
+        # host-side spans (null ctx when tracing is disabled).  The chunk
+        # span covers the host's work for one chunk: ``engine.dispatch``
+        # slices the batches and ``extra`` and enqueues the jitted chunk,
+        # which returns before the device finishes; ``engine.hooks`` runs
+        # the hooks and ``chunk_post``, which wait on the device only where
+        # they pull values to the host
         with _span("engine.chunk", start=done, size=n):
-            if batches is None:
-                key, chunk_batches = gen_batches(key, n)
-            elif slice_batches:
-                chunk_batches = jax.tree_util.tree_map(
+            with _span("engine.dispatch"):
+                if batches is None:
+                    key, chunk_batches = gen_batches(key, n)
+                elif slice_batches:
+                    chunk_batches = jax.tree_util.tree_map(
+                        lambda x: jax.lax.dynamic_slice_in_dim(x, done, n),
+                        batches)
+                else:
+                    chunk_batches = batches
+                chunk_extra = jax.tree_util.tree_map(
                     lambda x: jax.lax.dynamic_slice_in_dim(x, done, n),
-                    batches)
-            else:
-                chunk_batches = batches
-            chunk_extra = jax.tree_util.tree_map(
-                lambda x: jax.lax.dynamic_slice_in_dim(x, done, n), extra)
-            static = chunk_info(done, n) if chunk_info is not None else ()
-            state, aux = run_chunk(state, chunk_batches, chunk_extra, *static)
+                    extra)
+                static = chunk_info(done, n) if chunk_info is not None else ()
+                state, aux = run_chunk(state, chunk_batches, chunk_extra,
+                                       *static)
             done += n
             if host_rows:
                 aux = merge_host_aux(aux, {k: np.asarray(v[done - n:done])
                                            for k, v in host_rows.items()})
             if collect_aux:
                 aux_chunks.append(aux)
-            for hook in hooks:
-                hook(done, state, aux)
-            if chunk_post is not None:
-                state = chunk_post(done, state)
+            with _span("engine.hooks"):
+                for hook in hooks:
+                    hook(done, state, aux)
+                if chunk_post is not None:
+                    state = chunk_post(done, state)
     flush_hooks(hooks, done, state)
 
     if not aux_chunks:
         return state, None
-    stacked = jax.tree_util.tree_map(
-        lambda *xs: np.concatenate([np.asarray(x) for x in xs], axis=0),
-        *aux_chunks)
+    # the host waits on the device here: the aux of every chunk is copied
+    with _span("engine.fetch"):
+        stacked = jax.tree_util.tree_map(
+            lambda *xs: np.concatenate([np.asarray(x) for x in xs], axis=0),
+            *aux_chunks)
     return state, stacked
 
 

@@ -8,6 +8,7 @@ slices), and ``log_hook``'s printed format is byte-identical with the
 metrics registry wired in.
 """
 
+import glob
 import json
 import os
 import re
@@ -15,6 +16,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -36,7 +38,7 @@ from repro.obs.timeline import (
     validate_chrome_trace,
     write_chrome_trace,
 )
-from repro.obs.trace import Tracer, span, trace_hook, tracer
+from repro.obs.trace import Tracer, span, tracer
 from repro.train.engine import log_hook
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
@@ -82,15 +84,115 @@ def test_record_backfills_span_under_live_parent():
     assert tr.drain() and tr.spans == []  # drain clears the buffer
 
 
-def test_trace_hook_emits_one_span_per_chunk_boundary():
+# ---------------------------------------------------------------------------
+# profiler bridge: live spans land in the jax.profiler trace
+# ---------------------------------------------------------------------------
+#: the sampling executor's span names (the contract a profile is read by)
+EXECUTOR_SPANS = {"cluster.run", "cluster.schedule", "engine.chunk",
+                  "engine.dispatch", "engine.hooks", "engine.fetch"}
+
+
+def _profiled(tmp_path, body) -> list:
+    """Run ``body()`` under ``jax.profiler``; returns the host planes'
+    events as ``(name, start_ns, end_ns, stats)``."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in jax.profiler.ProfileData.from_file(path).planes
+            if plane.name.startswith("/host")
+            for line in plane.lines for e in line.events]
+
+
+def _tiny_cluster_run(steps=6, chunk=2):
+    from repro import samplers
+    from repro.cluster import ClusterEngine
+    from repro.core import Quadratic
+
+    quad = Quadratic.make(jax.random.PRNGKey(0), d=4, m=1.0, L=3.0)
+    sampler = samplers.sgld("consistent", lambda p, b: (quad.grad(p, b), 0.0),
+                            has_aux=True, gamma=0.01, sigma=0.5, tau=2)
+    engine = ClusterEngine(sampler, num_chains=2, chunk_size=chunk,
+                           collect_aux=True, donate=False)
+    state = engine.init(jnp.zeros(4), jax.random.PRNGKey(1))
+    schedule = np.minimum(np.arange(steps), 2)
+    engine.run(state, steps=steps, schedule=schedule)  # compile outside
+    return lambda: engine.run(state, steps=steps, schedule=schedule)
+
+
+def _inside(outer, inner) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_executor_spans_reach_the_profiler_host_plane(tmp_path):
+    run = _tiny_cluster_run(steps=6, chunk=2)
+    tr = tracer().enable()
+    tr.clear()
+    try:
+        events = _profiled(tmp_path, run)
+    finally:
+        tr.disable()
+        tr.clear()
+    by = {n: [e for e in events if e[0] == n] for n in EXECUTOR_SPANS}
+    (run_ev,), (sched,) = by["cluster.run"], by["cluster.schedule"]
+    assert run_ev[3] == {"steps": 6, "chains": 2}  # attrs become stats
+    assert _inside(run_ev, sched)
+    chunks = sorted(by["engine.chunk"], key=lambda e: e[1])
+    assert [c[3] for c in chunks] == [{"start": 0, "size": 2},
+                                      {"start": 2, "size": 2},
+                                      {"start": 4, "size": 2}]
+    assert len(by["engine.dispatch"]) == len(by["engine.hooks"]) == 3
+    for c in chunks:
+        assert _inside(run_ev, c)
+        assert sum(_inside(c, d) for d in by["engine.dispatch"]) == 1
+        assert sum(_inside(c, h) for h in by["engine.hooks"]) == 1
+    (fetch,) = by["engine.fetch"]
+    assert _inside(run_ev, fetch) and fetch[1] >= chunks[-1][2]
+
+
+def test_disabled_tracer_emits_no_profiler_event(tmp_path):
+    run = _tiny_cluster_run(steps=4, chunk=2)
+    assert not tracer().enabled
+    events = _profiled(tmp_path, run)
+    assert not EXECUTOR_SPANS & {e[0] for e in events}
+    assert tracer().spans == []
+
+
+def test_backfilled_span_stays_in_the_buffer(tmp_path):
     tr = Tracer(enabled=True)
-    hook = trace_hook(to=tr)
-    hook(50, None, None)
-    hook(100, None, None)
-    spans = tr.spans
-    assert [sp.attrs for sp in spans] == [{"start": 0, "end": 50},
-                                          {"start": 50, "end": 100}]
-    assert spans[0].t1 <= spans[1].t0  # contiguous boundary intervals
+
+    def body():
+        with tr.span("live.outer", k=1):
+            tr.record("backfilled", 0.0, 1.0)
+
+    names = {e[0] for e in _profiled(tmp_path, body)}
+    assert "live.outer" in names and "backfilled" not in names
+    assert {sp.name for sp in tr.spans} == {"live.outer", "backfilled"}
+
+
+def test_compiled_chunk_names_the_sampler_stages():
+    """The fused W-Con chunk's compiled text carries each stage's named
+    scope in its ops' metadata: the path a profile reads stages by."""
+    from repro import samplers
+    from repro.cluster import ClusterEngine
+    from repro.core import Quadratic
+
+    quad = Quadratic.make(jax.random.PRNGKey(0), d=4, m=1.0, L=3.0)
+    sampler = samplers.sgld("consistent", lambda p, b: quad.grad(p, b),
+                            gamma=0.01, sigma=0.5, tau=2, fused=True)
+    engine = ClusterEngine(sampler, num_chains=1, chunk_size=2,
+                           per_chain_batches=True)
+    state = engine.init(jnp.zeros(4), jax.random.PRNGKey(1))
+    text = engine.lower_chunk(state, jnp.zeros((2, 1, 1)),
+                              {"rv": jnp.zeros((2, 1), jnp.int32)}
+                              ).compile().as_text()
+    # the chain vmap wraps each scope: ".../vmap(fused_update)/..."
+    scopes = set(re.findall(r'op_name="jit\(chunk\)/[^"]*?[/(](delay_read|'
+                            r'gradients|fused_update)[/)]', text))
+    assert scopes == {"delay_read", "gradients", "fused_update"}
 
 
 # ---------------------------------------------------------------------------

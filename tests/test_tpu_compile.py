@@ -14,6 +14,7 @@ file must not take it.
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,21 @@ def test_langevin_update_compiles(one_chip, shape):
     seed = _on(one_chip, jax.ShapeDtypeStruct((2,), jnp.uint32))
     _compile(lambda x, g, s: lu.langevin_update_2d(x, g, s, 1e-3, 1e-2),
              x, x, seed)
+
+
+def test_langevin_update_kernel_is_named(one_chip):
+    """The kernel carries its own name: the Pallas call's ``kernel_name``
+    in the lowered program, and the compiled custom call's instruction
+    name, which a TPU trace shows as the operation's name."""
+    x = _on(one_chip, jax.ShapeDtypeStruct((2, D), jnp.bfloat16))
+    seed = _on(one_chip, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    lowered = jax.jit(
+        lambda x, g, s: lu.langevin_update_2d(x, g, s, 1e-3, 1e-2)
+    ).lower(x, x, seed)
+    assert 'kernel_name = "langevin_update"' in lowered.as_text()
+    assert re.search(r'%langevin_update(\.\d+)? = [^\n]*'
+                     r'custom_call_target="tpu_custom_call"',
+                     lowered.compile().as_text())
 
 
 def test_delay_gather_compiles(one_chip):
