@@ -15,6 +15,7 @@ from repro.kernels.ops import (
 )
 from repro.kernels.ref import delay_gather_ref, langevin_update_ref
 from repro.kernels.rng import normal_from_counter, threefry2x32
+from repro.utils import round_up
 
 
 # ---------------------------------------------------------------------------
@@ -60,6 +61,50 @@ def test_langevin_kernel_vs_ref(rows, cols, gamma, scale):
     want = langevin_update_ref(x, g, seed, gamma, scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+# Views that reach each branch of ``lu.tiling``: a block width that divides
+# the view (2560 -> 1280, 9728 -> 512, 4096 and 1024 -> 1024, 128), one that
+# cannot (999, 1: the masked edge), rows that are neither a multiple of the
+# strip nor of the block, and views with fewer rows than a strip.
+TILING_VIEWS = [(45, 2560), (40, 9728), (37, 4096), (41, 1024), (300, 128),
+                (300, 999), (300, 1), (2, 2560), (2, 128), (1, 1)]
+
+
+@pytest.mark.parametrize("rows,cols", TILING_VIEWS)
+def test_langevin_kernel_bitwise_equals_ref(rows, cols):
+    """The kernel's bits are the oracle's, whatever the blocking: the bf16
+    update (a leaf's dtype in the sampler), and in fp32 the noise alone,
+    which is the stream the benchmark's reference follows."""
+    seed = jnp.array([0x9E3779B9, 12345], jnp.uint32)
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cols), jnp.bfloat16)
+    g = jax.random.normal(jax.random.PRNGKey(cols), (rows, cols), jnp.bfloat16)
+    np.testing.assert_array_equal(
+        np.asarray(lu.langevin_update_2d(x, g, seed, 1e-3, 0.5)),
+        np.asarray(langevin_update_ref(x, g, seed, 1e-3, 0.5)))
+    zero = jnp.zeros((rows, cols), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(lu.langevin_update_2d(zero, zero, seed, 0.0, 1.0)),
+        np.asarray(langevin_update_ref(zero, zero, seed, 0.0, 1.0)))
+
+
+@pytest.mark.parametrize("rows,cols", TILING_VIEWS + [
+    (151936, 2560), (19456, 2560), (5120, 9728), (5120, 4096), (5120, 1024),
+    (4096, 1664), (1 << 20, 1)])
+def test_langevin_tiling(rows, cols):
+    """Blocks divide a lane-aligned width; strips hold at most ``STRIP``
+    elements, whole f32 vregs, and divide the block's rows."""
+    br, bc, sr = lu.tiling(rows, cols)
+    if cols % lu.LANE == 0:
+        assert cols % bc == 0 and bc % lu.LANE == 0 and bc <= lu.WIDEST
+    else:
+        assert bc == min(cols, lu.FALLBACK_COLS)
+    assert br % sr == 0 and br <= max(rows, sr)
+    if rows > sr:
+        assert sr % 8 == 0
+        assert sr * round_up(bc, lu.LANE) <= lu.STRIP
+    else:
+        assert br == sr == rows
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

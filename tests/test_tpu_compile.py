@@ -60,15 +60,41 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("shape", [(VOCAB, D), (2 * D, H * HD), (2, D)],
-                         ids=["embed", "stacked_wq", "norms"])
+# The fused update's call as the benchmark's roofline metric finds it in a
+# trace: a Pallas custom call whose scalar-prefetch operands are the s32[2]
+# seed, then the f32[2] (gamma, scale).  Copied from ``KERNEL`` in
+# benchmarks/chip/metrics/langevin_update_roofline.py.
+LANGEVIN_CALL = (r'custom-call\(s32\[2\]\{[^}]*\} [^,]+, f32\[2\]\{.*'
+                 r'custom_call_target="tpu_custom_call"')
+
+
+def _text_with_operand_shapes(compiled) -> str:
+    """The compiled program's text with each operand's shape before its
+    name, as a TPU trace names an operation (``as_text()`` prints the
+    operands' names alone)."""
+    from jax._src.lib import xla_client
+
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    return "\n".join(m.to_string(options)
+                     for m in compiled.runtime_executable().hlo_modules())
+
+
+@pytest.mark.parametrize("shape", [
+    (VOCAB, D), (2 * D, H * HD), (2, D), (2 * FF, D), (2 * D, FF),
+    (2 * D, KV * HD), (2, HD)],
+    ids=["embed", "stacked_wq", "norms", "w_down", "w_gate", "wk", "q_norm"])
 def test_langevin_update_compiles(one_chip, shape):
-    """The fused update over a leaf's 2-D view, in the leaf's dtype (bf16);
-    a ragged edge block and a view narrower than a block are fine."""
+    """The fused update over each of qwen3-4b's leaf views (two layers
+    stacked), in the leaf's dtype (bf16): blocks as wide as divides the
+    view, a ragged last row block, and views narrower than a strip.  The
+    call keeps the operands the roofline metric finds it by."""
     x = _on(one_chip, jax.ShapeDtypeStruct(shape, jnp.bfloat16))
     seed = _on(one_chip, jax.ShapeDtypeStruct((2,), jnp.uint32))
-    _compile(lambda x, g, s: lu.langevin_update_2d(x, g, s, 1e-3, 1e-2),
-             x, x, seed)
+    compiled = _compile(
+        lambda x, g, s: lu.langevin_update_2d(x, g, s, 1e-3, 1e-2),
+        x, x, seed)
+    assert re.search(LANGEVIN_CALL, _text_with_operand_shapes(compiled))
 
 
 def test_langevin_update_kernel_is_named(one_chip):
