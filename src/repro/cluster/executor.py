@@ -60,7 +60,8 @@ from repro.cluster.schedule import (
 )
 from repro.core.delay import validate_staleness
 from repro.core.delay_model import BATCH_POLICIES
-from repro.obs.metrics import STALENESS_BUCKETS, registry as _registry
+from repro.obs.metrics import EXPERT_LOAD_BUCKETS, STALENESS_BUCKETS, \
+    registry as _registry
 from repro.obs.trace import span as _span
 from repro.samplers.base import Sampler, SamplerState
 from repro.samplers.transforms import MaskedBatch
@@ -250,6 +251,12 @@ class ClusterEngine:
             "quarantined chains respawned from a healthy donor")
         self._m_unhealthy = reg.gauge(
             "chains.unhealthy", "chains currently quarantined")
+        self._m_moe_held = reg.counter(
+            "moe.assignments_held",
+            "(token, expert) assignments routed to the experts held here")
+        self._m_moe_load = reg.histogram(
+            "moe.expert_load", EXPERT_LOAD_BUCKETS,
+            "tokens one held expert of one MoE layer took in one commit")
 
     @property
     def num_traces(self) -> int:
@@ -628,7 +635,19 @@ class ClusterEngine:
 
     def _run(self, state, *, steps, **kw):
         with _span("cluster.run", steps=steps, chains=self.num_chains):
-            return self._drive(state, steps=steps, **kw)
+            state, aux = self._drive(state, steps=steps, **kw)
+        self._record_loads(aux)
+        return state, aux
+
+    def _record_loads(self, aux) -> None:
+        """The held experts' loads of an MoE model's commits (the grad fn's
+        ``expert_tokens``, (steps, C, layers, held)), when aux carries them:
+        one host copy of that array."""
+        if not isinstance(aux, dict) or "expert_tokens" not in aux:
+            return
+        loads = np.asarray(jax.device_get(aux["expert_tokens"]))
+        self._m_moe_held.inc(float(loads.sum()))
+        self._m_moe_load.observe_many(loads.ravel())
 
     def _schedule_inputs(self, state, schedule, steps, poison, base_steps):
         """-> (extra, commit_times, batch_info, base): the run's per-commit

@@ -22,6 +22,7 @@ ARCH_IDS = [
     "stablelm_12b",
     "qwen15_32b",
     "musicgen_medium",
+    "moonlight_16b_a3b",
 ]
 
 # canonical dashed ids (CLI) -> module names
@@ -36,6 +37,7 @@ ALIASES = {
     "stablelm-12b": "stablelm_12b",
     "qwen1.5-32b": "qwen15_32b",
     "musicgen-medium": "musicgen_medium",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 
@@ -60,11 +62,38 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     sliding_window: Optional[int] = None  # static window if set
 
-    # MoE
-    num_experts: int = 0
+    # latent attention (MLA, DeepSeek-V2/V3): k and v from a compressed
+    # latent of kv_lora_rank plus one rope key shared by all heads; q and k
+    # heads are qk_nope_head_dim + qk_rope_head_dim wide, v heads
+    # v_head_dim.  kv_lora_rank 0: ordinary multi-head attention.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # MoE (d_ff is one expert's width; the shared experts are one MLP of
+    # d_ff * num_shared_experts)
+    num_experts: int = 0           # routed experts the router scores
     experts_per_token: int = 0
     num_shared_experts: int = 0
     router_aux_coef: float = 0.01
+    # "softmax" (top-k of the softmax, Switch aux loss) or "sigmoid"
+    # (DeepSeek-V3 noaux_tc: top-k of sigmoid + score_correction_bias, gates
+    # the unbiased scores, no aux loss); gates are normalised over the k
+    router_score: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # per-MoE-layer e_score_correction_bias rows (num_experts floats each),
+    # a fixed buffer outside the sampled parameters; () reads as zeros
+    score_correction_bias: tuple = ()
+    # the experts this device holds: experts_held (0: all) starting at
+    # expert_offset.  The layer routes over all num_experts and returns the
+    # held experts' part of the result plus the shared experts.
+    experts_held: int = 0
+    expert_offset: int = 0
+    # leading dense layers (DeepSeek first_k_dense_replace) before the
+    # block_pattern stack, with an MLP of dense_d_ff
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
 
     # SSM (mamba-style heads: hymba) / xLSTM
     ssm_state: int = 0
@@ -96,6 +125,14 @@ class ArchConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         assert self.num_heads % self.num_kv_heads == 0 or self.num_kv_heads == 0
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score {self.router_score!r}")
+        if self.experts_held and (
+                self.expert_offset < 0 or self.expert_offset
+                + self.experts_held > self.num_experts):
+            last = self.expert_offset + self.experts_held - 1
+            raise ValueError(f"experts {self.expert_offset}..{last} are not "
+                             f"among {self.num_experts}")
 
     # -- derived -------------------------------------------------------------
     @property
@@ -105,6 +142,15 @@ class ArchConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def num_held(self) -> int:
+        """Routed experts held on this device."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
 
     def param_count(self) -> int:
         """Exact parameter count via eval_shape of the real init (cached)."""
@@ -128,9 +174,9 @@ class ArchConfig:
         d = self.d_model
         expert_p = 3 * d * self.d_ff
         n_moe_layers = sum(
-            1 for i in range(self.num_layers)
+            1 for i in range(self.num_moe_layers)
             if self.block_pattern[i % len(self.block_pattern)] == "attn_moe")
-        inactive = ((self.num_experts - self.experts_per_token)
+        inactive = ((self.num_held - self.experts_per_token)
                     * expert_p * n_moe_layers)
         return int(full - inactive)
 

@@ -153,3 +153,17 @@ def paged_decode_step_ref(q, k_new, v_new, k_pages, v_pages, tables, pos):
     return (jnp.stack(outs).reshape(q.shape),
             kf.at[widx].set(k_new).reshape(k_pages.shape),
             vf.at[widx].set(v_new).reshape(v_pages.shape))
+
+
+def grouped_matmul_ref(lhs, rhs, group_sizes):
+    """Oracle of ``kernels.grouped_matmul``: the same product through
+    ``jax.lax.ragged_dot`` (XLA's own grouped matmul), the rows past the
+    last group multiplied by a zero matrix."""
+    m = lhs.shape[0]
+    sizes = jnp.concatenate([group_sizes.astype(jnp.int32),
+                             (m - jnp.sum(group_sizes)).astype(jnp.int32)[None]])
+    rhs = jnp.concatenate([rhs.astype(lhs.dtype),
+                           jnp.zeros((1,) + rhs.shape[1:], lhs.dtype)])
+    return jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)

@@ -3,8 +3,10 @@
 ``flash_attention`` is a pure-JAX online-softmax implementation (lax.scan
 over query/key chunks) with a manual backward that recomputes per-block
 scores — O(S) memory at 32k/512k sequence lengths where a naive softmax
-would materialize S x S scores.  Supports causal masking, GQA and static
-sliding windows.  The naive path is the test oracle.
+would materialize S x S scores.  Supports causal masking, GQA, static
+sliding windows, and value heads narrower than the query/key heads (latent
+attention: 192-wide q and k, 128-wide v); the scale is 1/sqrt of the
+query's head width.  The naive path is the test oracle.
 """
 
 from __future__ import annotations
@@ -33,9 +35,10 @@ def _mask_block(q_pos, k_pos, causal: bool, window: Optional[int]):
 # reference implementation (oracle)
 # ---------------------------------------------------------------------------
 def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0):
-    """q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd).  fp32 softmax."""
+    """q: (B, Sq, H, hd); k: (B, Sk, KV, hd); v: (B, Sk, KV, hv).  fp32
+    softmax."""
     B, Sq, H, hd = q.shape
-    KV = k.shape[2]
+    KV, hv = k.shape[2], v.shape[-1]
     G = H // KV
     qh = q.reshape(B, Sq, KV, G, hd).astype(jnp.float32) / math.sqrt(hd)
     s = jnp.einsum("bqngh,bcnh->bngqc", qh, k.astype(jnp.float32))
@@ -45,7 +48,7 @@ def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0):
     s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bngqc,bcnh->bqngh", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, hd).astype(q.dtype)
+    return o.reshape(B, Sq, H, hv).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -58,14 +61,14 @@ def _n_win(window, k_chunk, nk):
 
 def _flash_fwd(q, k, v, causal, window, q_chunk, k_chunk, window_slice=False):
     B, Sq, H, hd = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, hv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     nq, nk = Sq // q_chunk, Sk // k_chunk
     scale = 1.0 / math.sqrt(hd)
 
     qc = q.reshape(B, nq, q_chunk, KV, G, hd)
     kc = k.reshape(B, nk, k_chunk, KV, hd)
-    vc = v.reshape(B, nk, k_chunk, KV, hd)
+    vc = v.reshape(B, nk, k_chunk, KV, hv)
     sliced = window_slice and window is not None and causal and nq == nk
 
     def q_step(_, qi):
@@ -106,7 +109,7 @@ def _flash_fwd(q, k, v, causal, window, q_chunk, k_chunk, window_slice=False):
 
         m0 = jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, q_chunk, hd), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, q_chunk, hv), jnp.float32)
         if sliced:
             nwin = _n_win(window, k_chunk, nk)
             (m, l, acc), _ = jax.lax.scan(k_step_sliced, (m0, l0, a0),
@@ -122,7 +125,7 @@ def _flash_fwd(q, k, v, causal, window, q_chunk, k_chunk, window_slice=False):
 
     _, (o, lse) = jax.lax.scan(q_step, None, (qc.swapaxes(0, 1), jnp.arange(nq)))
     # o: (nq, B, KV, G, qc, hd) -> (B, Sq, H, hd)
-    o = o.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, H, hd).astype(q.dtype)
+    o = o.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, H, hv).astype(q.dtype)
     # lse: (nq, B, KV, G, qc) -> (B, KV, G, Sq)
     lse = lse.transpose(1, 2, 3, 0, 4).reshape(B, KV, G, Sq)
     return o, lse
@@ -134,17 +137,17 @@ def _flash_fwd(q, k, v, causal, window, q_chunk, k_chunk, window_slice=False):
 def _flash_bwd_impl(q, k, v, o, lse, do, causal, window, q_chunk, k_chunk,
                     window_slice=False):
     B, Sq, H, hd = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, hv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     nq, nk = Sq // q_chunk, Sk // k_chunk
     scale = 1.0 / math.sqrt(hd)
 
     qc = q.reshape(B, nq, q_chunk, KV, G, hd).swapaxes(0, 1)
-    oc = o.reshape(B, nq, q_chunk, KV, G, hd).swapaxes(0, 1)
-    doc = do.reshape(B, nq, q_chunk, KV, G, hd).swapaxes(0, 1)
+    oc = o.reshape(B, nq, q_chunk, KV, G, hv).swapaxes(0, 1)
+    doc = do.reshape(B, nq, q_chunk, KV, G, hv).swapaxes(0, 1)
     lsec = lse.reshape(B, KV, G, nq, q_chunk).transpose(3, 0, 1, 2, 4)
     kc = k.reshape(B, nk, k_chunk, KV, hd)
-    vc = v.reshape(B, nk, k_chunk, KV, hd)
+    vc = v.reshape(B, nk, k_chunk, KV, hv)
 
     # delta = rowsum(do * o): (nq, B, KV, G, qc)
     delta = jnp.einsum("nbqkgh,nbqkgh->nbkgq",
@@ -199,12 +202,12 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal, window, q_chunk, k_chunk,
         return (dk_all, dv_all), dq
 
     dk0 = jnp.zeros((B, nk, k_chunk, KV, hd), jnp.float32)
-    dv0 = jnp.zeros((B, nk, k_chunk, KV, hd), jnp.float32)
+    dv0 = jnp.zeros((B, nk, k_chunk, KV, hv), jnp.float32)
     (dk, dv), dq = jax.lax.scan(
         q_step, (dk0, dv0), (qc, doc, lsec, delta, jnp.arange(nq)))
     dq = dq.swapaxes(0, 1).reshape(B, Sq, H, hd).astype(q.dtype)
     dk = dk.reshape(B, Sk, KV, hd).astype(k.dtype)
-    dv = dv.reshape(B, Sk, KV, hd).astype(v.dtype)
+    dv = dv.reshape(B, Sk, KV, hv).astype(v.dtype)
     # note: dk_b above used scaled q; ds already has the 1/sqrt(hd) folded via
     # qb32, so dk is correct as-is.
     return dq, dk, dv

@@ -5,14 +5,17 @@ One ``Model`` class covers all 10 assigned architectures via
 
 - ``attn_mlp``   dense decoder layer (llama-style; qk-norm / qkv-bias /
                  sliding-window per config)
-- ``attn_moe``   MoE decoder layer (expert-parallel, see moe.py)
+- ``attn_moe``   MoE decoder layer (dropless, expert-parallel, see moe.py)
 - ``hymba_mlp``  parallel attention + SSD heads (Hymba), then MLP
 - ``mlstm`` / ``slstm``  xLSTM blocks (no separate MLP)
 
 Homogeneous patterns (len == 1) stack layer parameters on a leading axis and
 run under ``lax.scan`` (compile-time O(1) in depth); heterogeneous patterns
-(xLSTM) use a python loop.  Every block is wrapped in ``jax.checkpoint`` for
-training memory.
+(xLSTM) use a python loop.  ``cfg.first_k_dense`` leading ``attn_mlp``
+layers (DeepSeek's ``first_k_dense_replace``, MLP width ``cfg.dense_d_ff``)
+run before the stack, one by one (``params["lead"]``).  Attention is latent
+(MLA, mla.py) where ``cfg.kv_lora_rank`` is set.  Every block is wrapped in
+``jax.checkpoint`` for training memory.
 
 Decode state is a dict of stacked-per-layer arrays so it threads through the
 same scan.  VLM/audio frontends are embedding stubs + a trainable projector
@@ -22,6 +25,7 @@ same scan.  VLM/audio frontends are embedding stubs + a trainable projector
 from __future__ import annotations
 
 import math
+from dataclasses import replace
 from typing import Any
 
 import jax
@@ -44,11 +48,15 @@ from repro.models.common import (
     partition_tree,
     rms_norm,
 )
+from repro.models.mla import apply_mla, init_mla
 from repro.models.mlp import apply_mlp, init_mlp
 
 PyTree = Any
 
 FRONTEND_DIM = 1024  # stub embedding width (ViT/EnCodec feature dim)
+MLA_SERVING = ("serving latent attention (MLA) or leading dense layers needs "
+               "a latent paged cache, which this model does not have; only "
+               "the training/sampling forward runs")
 
 
 # ===========================================================================
@@ -78,7 +86,8 @@ def init_block(key, cfg, block: str, dtype) -> dict:
     ks = jax.random.split(key, 4)
     p: dict = {"norm1": jnp.ones((cfg.d_model,), jnp.float32)}
     if block in ("attn_mlp", "attn_moe", "hymba_mlp"):
-        p["attn"] = init_attn(ks[0], cfg, dtype)
+        p["attn"] = (init_mla if cfg.kv_lora_rank else init_attn)(
+            ks[0], cfg, dtype)
         p["norm2"] = jnp.ones((cfg.d_model,), jnp.float32)
     if block == "hymba_mlp":
         p["ssm"] = ssm_lib.init_ssm(ks[1], cfg, dtype)
@@ -103,9 +112,15 @@ def init_params(key, cfg) -> PyTree:
     if cfg.frontend:
         params["frontend"] = {"proj": dense_init(k_front, (FRONTEND_DIM, cfg.d_model), dtype)}
 
+    if cfg.first_k_dense:
+        keys = jax.random.split(jax.random.fold_in(key, 5), cfg.first_k_dense)
+        dense = replace(cfg, d_ff=cfg.dense_d_ff)
+        params["lead"] = [init_block(k, dense, "attn_mlp", dtype)
+                          for k in keys]
+
     pattern = cfg.block_pattern
     if len(pattern) == 1:
-        keys = jax.random.split(k_stack, cfg.num_layers)
+        keys = jax.random.split(k_stack, cfg.num_moe_layers)
         params["stack"] = jax.vmap(
             lambda k: init_block(k, cfg, pattern[0], dtype))(keys)
     else:
@@ -247,8 +262,9 @@ def apply_paged_block(p, x, cfg, block: str, pages, tables, positions, *,
     x = x + rs * attn_out
     h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
     if block == "attn_moe":
-        ff, _ = moe_lib.apply_moe(p["moe"], h2, cfg, mesh=mesh,
-                                  batch_axes=batch_axes, fsdp_axes=fsdp_axes)
+        ff, _, _ = moe_lib.apply_moe(p["moe"], h2, cfg, mesh=mesh,
+                                     batch_axes=batch_axes,
+                                     fsdp_axes=fsdp_axes)
     else:
         ff = apply_mlp(p["mlp"], h2, cfg)
     x = x + rs * ff
@@ -257,18 +273,26 @@ def apply_paged_block(p, x, cfg, block: str, pages, tables, positions, *,
 
 def apply_block(p, x, cfg, block: str, positions, *, mesh=None, batch_axes=("data",),
                 fsdp_axes=("data",), cache=None, cur_pos=None, fused=False):
-    """Returns (x, aux_loss, new_cache)."""
+    """Returns (x, aux_loss, new_cache, tokens routed to each held expert:
+    (num_held,) int32 for an MoE block, else None)."""
     rs = cfg.residual_scale
     aux = jnp.float32(0.0)
+    load = None
     new_cache: dict = {}
     window = cfg.sliding_window
 
     if block in ("attn_mlp", "attn_moe", "hymba_mlp"):
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
-        attn_out, kv = apply_attn(p["attn"], h, cfg, positions, window=window,
-                                  cache=None if cache is None else cache["attn"],
-                                  cur_pos=cur_pos, mesh=mesh,
-                                  batch_axes=batch_axes, fused=fused)
+        if cfg.kv_lora_rank:
+            if cache is not None:
+                raise NotImplementedError(MLA_SERVING)
+            attn_out, kv = apply_mla(p["attn"], h, cfg, positions), None
+        else:
+            attn_out, kv = apply_attn(
+                p["attn"], h, cfg, positions, window=window,
+                cache=None if cache is None else cache["attn"],
+                cur_pos=cur_pos, mesh=mesh, batch_axes=batch_axes,
+                fused=fused)
         if block == "hymba_mlp":
             if cache is None:
                 ssm_out = ssm_lib.apply_ssm(p["ssm"], h, cfg)
@@ -284,13 +308,13 @@ def apply_block(p, x, cfg, block: str, positions, *, mesh=None, batch_axes=("dat
         x = x + rs * mix
         h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
         if block == "attn_moe":
-            ff, aux = moe_lib.apply_moe(p["moe"], h2, cfg, mesh=mesh,
-                                        batch_axes=batch_axes,
-                                        fsdp_axes=fsdp_axes)
+            ff, aux, load = moe_lib.apply_moe(p["moe"], h2, cfg, mesh=mesh,
+                                              batch_axes=batch_axes,
+                                              fsdp_axes=fsdp_axes)
         else:
             ff = apply_mlp(p["mlp"], h2, cfg)
         x = x + rs * ff
-        return x, aux, (new_cache if cache is not None else kv)
+        return x, aux, (new_cache if cache is not None else kv), load
 
     if block == "mlstm":
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -302,7 +326,7 @@ def apply_block(p, x, cfg, block: str, positions, *, mesh=None, batch_axes=("dat
             out, new_st = xlstm_lib.apply_mlstm(p["mlstm"], h, cfg, state=st)
             new_cache = {"mlstm_c": new_st.c, "mlstm_n": new_st.n,
                          "mlstm_m": new_st.m}
-        return x + rs * out, aux, new_cache
+        return x + rs * out, aux, new_cache, load
 
     if block == "slstm":
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -314,7 +338,7 @@ def apply_block(p, x, cfg, block: str, positions, *, mesh=None, batch_axes=("dat
             out, new_st = xlstm_lib.apply_slstm(p["slstm"], h, cfg, state=st)
             new_cache = {"slstm_c": new_st.c, "slstm_n": new_st.n,
                          "slstm_m": new_st.m, "slstm_h": new_st.h}
-        return x + rs * out, aux, new_cache
+        return x + rs * out, aux, new_cache, load
 
     raise ValueError(f"unknown block {block!r}")
 
@@ -359,9 +383,29 @@ class Model:
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return x @ w
 
+    def stack(self, params):
+        """The scanned layers' parameters, with the fixed routing buffer
+        (``cfg.score_correction_bias``, zeros where none is given) put
+        into each sigmoid-routed MoE layer: a constant of the program, not
+        a parameter."""
+        cfg = self.cfg
+        stack = params["stack"]
+        if cfg.router_score != "sigmoid" or "moe" not in stack:
+            return stack
+        bias = (jnp.asarray(cfg.score_correction_bias, jnp.float32)
+                if cfg.score_correction_bias else
+                jnp.zeros((cfg.num_moe_layers, cfg.num_experts), jnp.float32))
+        return dict(stack, moe=dict(stack["moe"], **{moe_lib.BIAS: bias}))
+
     # -- forward over layers ----------------------------------------------------
     def forward(self, params, batch, want_kv: bool = False):
         """Train/prefill forward. Returns (logits, aux, kv-stack or None)."""
+        return self.forward_loads(params, batch, want_kv)[:3]
+
+    def forward_loads(self, params, batch, want_kv: bool = False):
+        """:meth:`forward` and, for MoE stacks, the tokens routed to each
+        held expert of each MoE layer ((moe layers, num_held) int32, else
+        None)."""
         cfg = self.cfg
         x, positions = self.embed(params, batch)
 
@@ -375,15 +419,18 @@ class Model:
                                       policy=jax.checkpoint_policies.nothing_saveable)
 
         aux_total = jnp.float32(0.0)
-        kvs = None
+        kvs = loads = None
+        for layer_p in params.get("lead", ()):
+            x, a, _, _ = block_fn(layer_p, x, "attn_mlp")
+            aux_total = aux_total + a
         if "stack" in params and cfg.opt_unroll_layers:
             # §Perf: unrolled layers — each FSDP all-gather is a per-layer
             # slice instead of a full-stack gather inside the scan
             kvs = []
-            for i in range(cfg.num_layers):
-                layer_p = jax.tree_util.tree_map(lambda a, i=i: a[i],
-                                                 params["stack"])
-                x, a, kv = block_fn(layer_p, x, cfg.block_pattern[0])
+            stack = self.stack(params)
+            for i in range(cfg.num_moe_layers):
+                layer_p = jax.tree_util.tree_map(lambda a, i=i: a[i], stack)
+                x, a, kv, _ = block_fn(layer_p, x, cfg.block_pattern[0])
                 aux_total = aux_total + a
                 kvs.append(kv if want_kv else None)
             kvs = None if not want_kv else jax.tree_util.tree_map(
@@ -393,25 +440,26 @@ class Model:
 
             def scan_body(carry, layer_p):
                 x, aux = carry
-                x, a, kv = block_fn(layer_p, x, block)
-                return (x, aux + a), (kv if want_kv else None)
+                x, a, kv, load = block_fn(layer_p, x, block)
+                return (x, aux + a), (kv if want_kv else None, load)
 
-            (x, aux_total), kvs = jax.lax.scan(scan_body, (x, aux_total),
-                                               params["stack"])
+            (x, aux_total), (kvs, loads) = jax.lax.scan(
+                scan_body, (x, aux_total), self.stack(params))
         else:
             kvs = []
             for i, layer_p in enumerate(params["layers"]):
                 block = cfg.block_pattern[i % len(cfg.block_pattern)]
-                x, a, kv = block_fn(layer_p, x, block)
+                x, a, kv, _ = block_fn(layer_p, x, block)
                 aux_total = aux_total + a
                 kvs.append(kv if want_kv else None)
         logits = self.unembed(params, x)
-        return logits, aux_total / cfg.num_layers, kvs
+        return logits, aux_total / cfg.num_layers, kvs, loads
 
     # -- decode -------------------------------------------------------------------
     def init_cache(self, batch_size: int, max_seq: int, prefill_len: int = 0):
         """Decode cache, stacked per layer (scan-compatible)."""
         cfg = self.cfg
+        self._refuse_latent("init_cache")
         dtype = dtype_of(cfg)
         L = cfg.num_layers
         window = cfg.sliding_window
@@ -448,8 +496,13 @@ class Model:
         return [entry_for(cfg.block_pattern[i % len(cfg.block_pattern)])
                 for i in range(L)]
 
+    def _refuse_latent(self, what: str):
+        if self.cfg.kv_lora_rank or self.cfg.first_k_dense:
+            raise NotImplementedError(f"{what}: {MLA_SERVING}")
+
     def _require_stacked_attention(self, what: str):
         cfg = self.cfg
+        self._refuse_latent(what)
         if len(cfg.block_pattern) != 1 or cfg.block_pattern[0] not in (
                 "attn_mlp", "attn_moe"):
             raise ValueError(
@@ -555,7 +608,7 @@ class Model:
                 fsdp_axes=self.fsdp_axes, fused=self.decode_fused)
             return x, new_pg
 
-        x, new_pages = jax.lax.scan(scan_body, x, (params["stack"], pages))
+        x, new_pages = jax.lax.scan(scan_body, x, (self.stack(params), pages))
         logits = self.unembed(params, x)
         return logits, new_pages
 
@@ -613,15 +666,16 @@ class Model:
 
             def scan_body(x, inp):
                 layer_p, c = inp
-                x, _, new_c = block_fn(layer_p, x, block, c)
+                x, _, new_c, _ = block_fn(layer_p, x, block, c)
                 return x, new_c
 
-            x, new_cache = jax.lax.scan(scan_body, x, (params["stack"], cache))
+            x, new_cache = jax.lax.scan(scan_body, x,
+                                        (self.stack(params), cache))
         else:
             new_cache = []
             for i, layer_p in enumerate(params["layers"]):
                 block = cfg.block_pattern[i % len(cfg.block_pattern)]
-                x, _, c = block_fn(layer_p, x, block, cache[i])
+                x, _, c, _ = block_fn(layer_p, x, block, cache[i])
                 new_cache.append(c)
         logits = self.unembed(params, x)
         return logits, new_cache
@@ -636,6 +690,7 @@ class Model:
         in the dry-run lowers this forward pass, which is the expensive part.
         """
         cfg = self.cfg
+        self._refuse_latent("prefill")
         logits, _, kvs = self.forward(params, batch, want_kv=True)
         window = cfg.sliding_window
         if "stack" in params and cfg.block_pattern[0] in ("attn_mlp", "attn_moe"):
@@ -655,12 +710,14 @@ class Model:
 # ===========================================================================
 def loss_fn(model: Model, params, batch) -> tuple[jnp.ndarray, dict]:
     """Next-token cross-entropy (+ MoE aux).  batch carries 'tokens' (B, S+1)
-    and optionally 'frontend'; loss is computed on token positions only."""
+    and optionally 'frontend'; loss is computed on token positions only.
+    The metrics of an MoE stack carry ``expert_tokens``: the tokens routed
+    to each held expert of each MoE layer ((moe layers, num_held) int32)."""
     cfg = model.cfg
     tokens = batch["tokens"]
     inp = dict(batch)
     inp["tokens"] = tokens[:, :-1]
-    logits, aux, _ = model.forward(params, inp)
+    logits, aux, _, loads = model.forward_loads(params, inp)
     labels = tokens[:, 1:]
     n_text = labels.shape[1]
     logits_text = logits[:, -n_text:]  # skip frontend positions
@@ -668,7 +725,10 @@ def loss_fn(model: Model, params, batch) -> tuple[jnp.ndarray, dict]:
     ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     ce = -jnp.mean(ll)
     total = ce + cfg.router_aux_coef * aux
-    return total, {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": aux}
+    if loads is not None:
+        metrics["expert_tokens"] = loads
+    return total, metrics
 
 
 partition_tree = partition_tree  # re-export for repro.models namespace

@@ -35,7 +35,8 @@ import threading
 from typing import Optional, Sequence
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "registry",
-           "LATENCY_MS_BUCKETS", "STALENESS_BUCKETS"]
+           "EXPERT_LOAD_BUCKETS", "LATENCY_MS_BUCKETS",
+           "STALENESS_BUCKETS"]
 
 #: default rungs for millisecond-latency histograms (log-ish ladder)
 LATENCY_MS_BUCKETS = (0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
@@ -43,6 +44,8 @@ LATENCY_MS_BUCKETS = (0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 #: default rungs for per-commit staleness (powers of two; tau=0 is its own
 #: bucket so the synchronous baseline is visible at a glance)
 STALENESS_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128)
+#: tokens one expert of one MoE layer took in one commit
+EXPERT_LOAD_BUCKETS = (0, 16, 64, 256, 1024, 2048, 4096, 8192, 16384, 65536)
 
 
 class Counter:

@@ -44,16 +44,23 @@ def make_grad_fn(model: Model, num_microbatches: int = 1):
     def accumulated(params, batch):
         micro = _split_microbatch(batch, num_microbatches)
 
+        def add(a, b):  # float metrics are means, counts are sums
+            if jnp.issubdtype(a.dtype, jnp.integer):
+                return a + b
+            return a + b / num_microbatches
+
         def body(carry, mb):
             g_acc, m_acc = carry
             g, m = single(params, mb)
             g_acc = tree_add_scaled(g_acc, g, 1.0 / num_microbatches)
-            m_acc = jax.tree_util.tree_map(
-                lambda a, b: a + b / num_microbatches, m_acc, m)
+            m_acc = jax.tree_util.tree_map(add, m_acc, m)
             return (g_acc, m_acc), None
 
         g0 = tree_zeros_like(params)
-        m0 = {"ce": jnp.float32(0), "aux": jnp.float32(0), "loss": jnp.float32(0)}
+        m0 = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(single, params,
+                           jax.tree_util.tree_map(lambda x: x[0], micro))[1])
         (grads, metrics), _ = jax.lax.scan(body, (g0, m0), micro)
         return grads, metrics
 

@@ -1,5 +1,6 @@
 """MoE routing / dispatch correctness (local path; sharded path covered by
-test_sharding subprocess tests)."""
+test_sharding subprocess tests; DeepSeek-V3 gating and the expert share
+against a reference in test_mla_moe)."""
 
 from dataclasses import replace
 
@@ -9,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_reduced
-from repro.models.moe import _moe_local, apply_moe, capacity, init_moe
+from repro.models.moe import _layer_local, apply_moe, init_moe, route
 from repro.models.common import activation
 
 
@@ -27,8 +28,7 @@ def test_top1_routing_selects_expert(cfg):
     router = jnp.zeros((d, 4)).at[0, 0].set(10.0).at[0, 1].set(-10.0)
     p = dict(p, router=router)
     xt = jnp.zeros((8, d)).at[:4, 0].set(1.0).at[4:, 0].set(-1.0)
-    out, aux = _moe_local(p, xt, cfg1, 4, 0, capacity(8, cfg1),
-                          activation(cfg1.act))
+    out, aux, _ = _layer_local(p, xt, cfg1, 4, 0, activation(cfg1.act))
     # expert 0 processes tokens 0..3, expert 1 tokens 4..7: outputs within
     # each group identical, across groups different
     o = np.asarray(out)
@@ -37,20 +37,31 @@ def test_top1_routing_selects_expert(cfg):
     assert np.abs(o[0] - o[4]).max() > 1e-4
 
 
-def test_capacity_drop(cfg):
-    """Tokens beyond expert capacity are dropped, not mis-routed."""
-    cfg1 = replace(cfg, experts_per_token=1, num_experts=4)
-    p = init_moe(jax.random.PRNGKey(1), cfg1, jnp.float32)
+@pytest.mark.parametrize("held,offset", [(4, 0), (2, 0), (2, 2)])
+def test_dropless_when_every_token_picks_one_expert(cfg, held, offset):
+    """Every token routed to expert 0, far past any capacity a buffer of
+    the mean load would give: all are processed where expert 0 is held, and
+    none is where it is held elsewhere."""
+    cfg1 = replace(cfg, experts_per_token=1, num_experts=4,
+                   num_shared_experts=0)
+    p = init_moe(jax.random.PRNGKey(1), replace(cfg1, experts_held=4),
+                 jnp.float32)
     d = cfg1.d_model
     router = jnp.zeros((d, 4)).at[0, 0].set(10.0)  # everything -> expert 0
-    p = dict(p, router=router)
-    xt = jnp.ones((32, d))
-    cap = 4
-    out, _ = _moe_local(p, xt, cfg1, 4, 0, cap, activation(cfg1.act))
+    p = dict(p, router=router, **{k: p[k][offset:offset + held]
+                                  for k in ("w_gate", "w_up", "w_down")})
+    xt = jax.random.normal(jax.random.PRNGKey(2), (32, d)).at[:, 0].set(1.0)
+    out, _, load = _layer_local(p, xt, cfg1, held, offset,
+                                activation(cfg1.act))
     o = np.asarray(out)
-    # exactly cap tokens processed; the rest got zero contribution
-    nonzero = (np.abs(o).max(axis=1) > 1e-7).sum()
-    assert nonzero == cap
+    if offset == 0:
+        np.testing.assert_array_equal(load, [32] + [0] * (held - 1))
+        h = jax.nn.silu(xt @ p["w_gate"][0]) * (xt @ p["w_up"][0])
+        np.testing.assert_allclose(o, h @ p["w_down"][0], rtol=1e-5,
+                                   atol=1e-5)
+    else:
+        np.testing.assert_array_equal(load, [0] * held)
+        assert not o.any()
 
 
 def test_aux_loss_uniform_router_is_one(cfg):
@@ -62,8 +73,7 @@ def test_aux_loss_uniform_router_is_one(cfg):
     # expert 0) -> aux = E * (1 * 1/E) = 1 for probs, frac_tokens=e0=1:
     # aux = E * sum(frac_tokens * frac_probs) = 4 * (1*0.25) = 1
     xt = jax.random.normal(jax.random.PRNGKey(3), (64, cfg1.d_model)) * 0.0
-    _, aux = _moe_local(p, xt, cfg1, 4, 0, capacity(64, cfg1),
-                        activation(cfg1.act))
+    _, _, aux = route(p, xt, cfg1)
     assert float(aux) == pytest.approx(1.0, rel=1e-3)
 
 
@@ -73,7 +83,7 @@ def test_moe_apply_differentiable(cfg):
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 8, cfg1.d_model))
 
     def loss(p):
-        y, aux = apply_moe(p, x, cfg1, mesh=None)
+        y, aux, _ = apply_moe(p, x, cfg1, mesh=None)
         return jnp.sum(y**2) + aux
 
     g = jax.grad(loss)(p)
@@ -88,7 +98,7 @@ def test_shared_expert_contributes(cfg):
                    num_shared_experts=1)
     p = init_moe(jax.random.PRNGKey(6), cfg1, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(7), (1, 4, cfg1.d_model))
-    y1, _ = apply_moe(p, x, cfg1, mesh=None)
+    y1, _, _ = apply_moe(p, x, cfg1, mesh=None)
     p2 = dict(p, shared_w_down=jnp.zeros_like(p["shared_w_down"]))
-    y2, _ = apply_moe(p2, x, cfg1, mesh=None)
+    y2, _, _ = apply_moe(p2, x, cfg1, mesh=None)
     assert float(jnp.abs(y1 - y2).max()) > 1e-5
