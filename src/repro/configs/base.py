@@ -116,8 +116,6 @@ class ArchConfig:
     # paper-faithful baseline) -------------------------------------------------
     opt_attn_head_shard: bool = False  # shard q-heads / replicate kv: no
                                        # GSPMD resharding inside flash loops
-    opt_window_slice: bool = False     # sliding-window flash reads only the
-                                       # in-window k/v chunks (dyn. slice)
     opt_unroll_layers: bool = False    # python-loop layers instead of scan
                                        # (FSDP: per-layer slice gathers)
 

@@ -155,7 +155,8 @@ def main(argv=None):
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--mode", default="sync", choices=["sync", "pipeline"])
-    ap.add_argument("--opts", default="", help="comma list: attn_shard,window_slice")
+    ap.add_argument("--opts", default="",
+                    help="comma list: attn_shard,fsdp,unroll,padvocab")
     ap.add_argument("--micro", type=int, default=0,
                     help="override num_microbatches (train shapes)")
     ap.add_argument("--outdir", default=OUTDIR)

@@ -42,14 +42,12 @@ def adapt_config(cfg: ArchConfig, shape: ShapeConfig,
                  opts: tuple = ()) -> ArchConfig:
     """Shape-dependent config tweaks (DESIGN.md §4) + §Perf opt switches.
 
-    opts: subset of {"attn_shard", "window_slice"}."""
+    opts: subset of {"attn_shard", "fsdp", "unroll", "padvocab"}."""
     if shape.name == "long_500k" and cfg.family not in ("ssm",) \
             and cfg.sliding_window is None:
         cfg = replace(cfg, sliding_window=LONG_CONTEXT_WINDOW)
     if "attn_shard" in opts:
         cfg = replace(cfg, opt_attn_head_shard=True)
-    if "window_slice" in opts:
-        cfg = replace(cfg, opt_window_slice=True)
     if "fsdp" in opts:
         assert cfg.num_experts == 0, "fsdp opt is for dense archs"
         cfg = replace(cfg, param_sharding="fsdp_full",

@@ -176,8 +176,7 @@ def apply_attn(p, x, cfg, positions, *, window, cache=None, cur_pos=None,
         v = jax.lax.with_sharding_constraint(v, hspec)
 
     if cache is None:  # train / prefill
-        o = attention_any(q, k, v, causal=True, window=window,
-                          window_slice=cfg.opt_window_slice)
+        o = attention_any(q, k, v, causal=True, window=window)
         new_kv = (k, v)
     else:  # decode: S == 1
         smax = cache["k"].shape[1]
