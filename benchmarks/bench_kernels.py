@@ -16,8 +16,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.core.sgld import apply_update, langevin_noise
 from repro.kernels.ref import langevin_update_ref, delay_gather_ref
+from repro.samplers.transforms import noise_like, sgld_apply
 
 
 def _time(fn, *args, iters=20):
@@ -38,8 +38,8 @@ def run(n: int = 1 << 20):
     # unfused XLA path (jax.random.normal + update)
     @jax.jit
     def unfused(x, g, key):
-        noise = langevin_noise(key, {"p": x}, jnp.float32(0.05), jnp.float32)
-        return apply_update({"p": x}, {"p": g}, jnp.float32(0.01), noise)["p"]
+        noise = noise_like(key, {"p": x}, jnp.float32(0.05), jnp.float32)
+        return sgld_apply({"p": x}, {"p": g}, jnp.float32(0.01), noise)["p"]
 
     us_unfused = _time(unfused, x, g, jax.random.PRNGKey(2))
 

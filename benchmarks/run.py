@@ -1,4 +1,4 @@
-"""Benchmark runner: one module per paper table/figure family + roofline.
+"""Benchmark runner: one module per paper table/figure family.
 
 ``python -m benchmarks.run``           fast pass, prints CSV
 ``python -m benchmarks.run --full``    full paper grids (slow, writes JSONs)
@@ -18,7 +18,6 @@ from benchmarks import (
     bench_kernels,
     bench_regression,
     bench_rica,
-    bench_roofline,
     bench_serve,
     bench_speedup,
     bench_tau_sweep,
@@ -34,7 +33,6 @@ BENCHES = {
     "cluster": bench_cluster.main,         # C-chain ensemble W2 + speedup
     "serve": bench_serve.main,             # chain-bank predictive serving
     "decode": bench_decode.main,           # streaming BMA decode tokens/sec
-    "roofline": bench_roofline.main,       # §Roofline table (from dry-run)
 }
 
 

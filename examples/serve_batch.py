@@ -14,11 +14,11 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import samplers
 from repro.configs import ShapeConfig, get_reduced
-from repro.core import SGLDConfig
 from repro.data import make_batch
 from repro.models.transformer import Model, init_params
-from repro.train import Engine, make_train_step
+from repro.train import Engine, make_grad_fn
 
 
 def main():
@@ -40,8 +40,8 @@ def main():
 
     # a few SGLD steps so the served weights are a posterior sample
     shape = ShapeConfig("warm", seq_len=64, global_batch=2, kind="train")
-    sampler, _ = make_train_step(
-        model, SGLDConfig(mode="pipeline", gamma=1e-3, sigma=1e-8))
+    sampler = samplers.sgld("pipeline", make_grad_fn(model), has_aux=True,
+                            gamma=1e-3, sigma=1e-8)
     if args.warm_steps > 0:
         key, init_key = jax.random.split(key)
         state = sampler.init(params, init_key)

@@ -17,10 +17,11 @@ import numpy as np
 
 from repro.checkpoint import save_checkpoint
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.core import SGLDConfig, WorkerModel, simulate_async
+from repro import samplers
+from repro.core import WorkerModel, simulate_async
 from repro.data import make_batch
 from repro.models.transformer import Model, init_params
-from repro.train import Engine, checkpoint_hook, make_train_step
+from repro.train import Engine, checkpoint_hook, make_grad_fn
 
 LM_100M = ArchConfig(
     name="lm-100m",
@@ -63,10 +64,10 @@ def main():
     print(f"{cfg.name}: {n/1e6:.1f}M params, mode={args.mode}, "
           f"tokens/step={args.batch * args.seq}")
 
-    sgld = SGLDConfig(
-        mode=args.mode, gamma=args.gamma, sigma=args.sigma,
+    sampler = samplers.sgld(
+        args.mode, make_grad_fn(model), has_aux=True, gamma=args.gamma,
+        sigma=args.sigma,
         tau=args.tau if args.mode in ("consistent", "inconsistent") else 0)
-    sampler, _ = make_train_step(model, sgld)
     key, init_key = jax.random.split(key)
     state = sampler.init(params, init_key)
 

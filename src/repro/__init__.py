@@ -3,7 +3,7 @@
 A production-grade JAX framework reproducing Kungurtsev, Chatterjee, Alistarh
 (2020): delayed-gradient SGLD (Sync / W-Con / W-Icon) as a first-class
 distributed sampler, plus the substrate (model zoo, data pipeline,
-checkpointing, launcher, multi-pod sharding) needed to run it at scale.
+checkpointing, launcher, sharding) needed to run it at scale.
 """
 
 __version__ = "0.1.0"

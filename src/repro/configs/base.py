@@ -112,13 +112,6 @@ class ArchConfig:
     # distribution
     param_sharding: str = "tp"      # "tp" | "fsdp_tp" (2-D for trillion-scale)
 
-    # ---- beyond-paper performance switches (§Perf hillclimb; default off =
-    # paper-faithful baseline) -------------------------------------------------
-    opt_attn_head_shard: bool = False  # shard q-heads / replicate kv: no
-                                       # GSPMD resharding inside flash loops
-    opt_unroll_layers: bool = False    # python-loop layers instead of scan
-                                       # (FSDP: per-layer slice gathers)
-
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
@@ -187,15 +180,6 @@ class ShapeConfig:
     seq_len: int
     global_batch: int
     kind: str            # "train" | "prefill" | "decode"
-    num_microbatches: int = 1
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train", num_microbatches=4),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
 
 
 def get_arch(name: str) -> ArchConfig:
@@ -208,10 +192,6 @@ def get_reduced(name: str) -> ArchConfig:
     mod_name = ALIASES.get(name, name).replace("-", "_").replace(".", "p")
     mod = importlib.import_module(f"repro.configs.{mod_name}")
     return mod.reduced()
-
-
-def get_shape(name: str) -> ShapeConfig:
-    return SHAPES[name]
 
 
 def all_archs() -> list[ArchConfig]:

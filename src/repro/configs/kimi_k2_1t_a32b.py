@@ -3,8 +3,7 @@
 Assigned: 61L d_model=7168 64H (GQA kv=8) d_ff=2048(per expert)
 vocab=163840, MoE 384 experts top-8 (+1 shared, DeepSeek-V3 lineage).
 At 1T total parameters this arch *requires* 2-D parameter sharding
-(``fsdp_tp``): experts over the model axis and d_ff over the data axis —
-single-pod HBM accounting is reported in EXPERIMENTS.md §Dry-run.
+(``fsdp_tp``): experts over the model axis and d_ff over the data axis.
 """
 
 from repro.configs.base import ArchConfig, _reduce_common

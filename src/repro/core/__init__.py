@@ -25,7 +25,6 @@ from repro.core.delay_model import (  # noqa: F401
 )
 from repro.core.potentials import PolyRegression, Quadratic, RICA  # noqa: F401
 from repro.core.schedules import clip_to_theory, constant, poly_decay, wsd  # noqa: F401
-from repro.core.sgld import SGLDConfig, SGLDSampler, SGLDState  # noqa: F401
 from repro.core.theory import (  # noqa: F401
     ProblemConstants,
     gamma_eps_kl,

@@ -1,9 +1,9 @@
 """Host-side data pipeline: double-buffered prefetch + device placement.
 
-The dry-run shapes never allocate, but the real training loop wants batches
-produced off the critical path: ``Prefetcher`` generates the next batch on a
-background thread while the current step runs, and (when a mesh is given)
-places it with the batch sharding the step expects.  :func:`ar1_stream`
+The training loop wants batches produced off the critical path:
+``Prefetcher`` generates the next batch on a background thread while the
+current step runs, and (when a mesh is given) places it with the batch
+sharding the step expects.  :func:`ar1_stream`
 generates the dependent (non-i.i.d.) minibatch sequence used by the
 Chau-et-al.-shaped benchmark scenario.
 """

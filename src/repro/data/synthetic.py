@@ -1,8 +1,7 @@
 """Synthetic data: token streams and frontend-embedding stubs.
 
-``make_batch`` returns real arrays (smoke tests / examples);
-``make_specs`` returns ShapeDtypeStruct stand-ins for the dry-run (the
-"weak-type-correct, shardable, no device allocation" pattern).
+``make_batch`` returns real arrays for an (arch, shape) pair: the
+launcher's batches, the smoke tests' and the examples'.
 
 Frontend stubs (the one allowed stub): VLM batches carry precomputed patch
 embeddings, audio batches carry precomputed frame embeddings, both of width
@@ -53,23 +52,3 @@ def make_batch(cfg, shape, key, kind: str | None = None):
                 "cur_pos": jnp.int32(shape.seq_len - 1)}
     raise ValueError(kind)
 
-
-def make_specs(cfg, shape, kind: str | None = None):
-    """ShapeDtypeStruct stand-ins (dry-run; no allocation)."""
-    kind = kind or shape.kind
-    B = shape.global_batch
-    sds = jax.ShapeDtypeStruct
-
-    if kind in ("train", "prefill"):
-        s_text = _text_len(cfg, shape)
-        extra = 1 if kind == "train" else 0
-        batch = {"tokens": sds((B, s_text + extra), jnp.int32)}
-        if cfg.frontend:
-            batch["frontend"] = sds((B, cfg.num_frontend_tokens, FRONTEND_DIM),
-                                    jnp.float32)
-        return batch
-
-    if kind == "decode":
-        return {"tokens": sds((B, 1), jnp.int32),
-                "cur_pos": sds((), jnp.int32)}
-    raise ValueError(kind)

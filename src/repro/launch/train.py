@@ -5,13 +5,11 @@ Real-hardware entry point (and CPU-reduced driver with --reduced):
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --reduced \
         --steps 50 --mode pipeline --batch 8 --seq 128
 
-Training runs through the unified scan-chunked Engine: one jitted dispatch
-per --chunk steps, delays fed as device arrays (no per-delay retraces), and
---fused commits through the Pallas fused Langevin kernel.
-
-On a TPU slice, omit --reduced: the production mesh is built, parameters are
-initialized sharded (init under jit with out_shardings), and the train step
-runs under the mesh with the shape's microbatching.
+The sampler is the ``repro.samplers.sgld`` preset for --mode, and training
+runs through the unified scan-chunked Engine: one jitted dispatch per
+--chunk steps, delays fed as device arrays (no per-delay retraces), and
+--fused commits through the Pallas fused Langevin kernel.  Without
+--reduced the arch runs at its published widths on one device.
 """
 
 from __future__ import annotations
@@ -21,17 +19,19 @@ import argparse
 import jax
 import numpy as np
 
+from repro import samplers
 from repro.configs import ShapeConfig, get_arch, get_reduced
 from repro.core import WorkerModel, simulate_async
-from repro.core.sgld import SGLDConfig
 from repro.data import make_batch
 from repro.models.transformer import Model, init_params
 from repro.train.engine import Engine, checkpoint_hook, log_hook
-from repro.train.loop import make_train_step
+from repro.train.loop import make_grad_fn
 from repro.utils import enable_compile_cache
 
 
 def main(argv=None):
+    """Parse ``argv`` (``sys.argv`` when None), train, and return the
+    final sampler state."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -66,15 +66,15 @@ def main(argv=None):
     print(f"{cfg.name}: {n_params/1e6:.1f}M params, mode={args.mode}"
           f"{' (fused)' if args.fused else ''}, chunk={args.chunk}")
 
-    sgld_cfg = SGLDConfig(mode=args.mode, gamma=args.gamma, sigma=args.sigma,
-                          tau=args.tau if args.mode in ("consistent",
-                                                        "inconsistent") else 0)
-    sampler, _ = make_train_step(model, sgld_cfg, fused=args.fused)
+    stale = args.mode in ("consistent", "inconsistent")
+    sampler = samplers.sgld(args.mode, make_grad_fn(model), has_aux=True,
+                            gamma=args.gamma, sigma=args.sigma,
+                            tau=args.tau if stale else 0, fused=args.fused)
     key, init_key = jax.random.split(key)
     state = sampler.init(params, init_key)
 
     delays = None
-    if args.mode in ("consistent", "inconsistent"):
+    if stale:
         trace = simulate_async(WorkerModel(num_workers=args.workers,
                                            seed=args.seed), args.steps,
                                seed=args.seed)
@@ -91,6 +91,7 @@ def main(argv=None):
         from repro.checkpoint import save_checkpoint
         save_checkpoint(args.save, state.params, step=args.steps)
         print("saved", args.save)
+    return state
 
 
 if __name__ == "__main__":

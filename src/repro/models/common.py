@@ -86,17 +86,6 @@ def partition_rules(param_sharding: str, fsdp_axes=("data",), cfg=None,
     mdl = "model"
     fsdp = fsdp_axes  # secondary axes for trillion-scale 2-D sharding
     two_d = param_sharding == "fsdp_tp"
-    if param_sharding == "fsdp_full":
-        # §Perf O3: pure FSDP/ZeRO-3 — every weight sharded over ALL
-        # data-like+model axes (gathered per layer), batch over all axes,
-        # no tensor-parallel activation all-reduces at all.
-        mdl = tuple(fsdp_axes) + ("model",)
-    # §Perf O1 layout: q heads shard over model (when divisible), k/v params
-    # replicate (activations repeated to H heads inherit q's sharding)
-    head_shard = bool(cfg is not None and getattr(cfg, "opt_attn_head_shard",
-                                                  False))
-    q_shardable = bool(head_shard and model_size
-                       and cfg.num_heads % model_size == 0)
     # Never shard an attention projection finer than its head boundary:
     # splitting one head's head_dim across devices forces cross-shard
     # resharding inside rope/norm/attention (and miscompiles on some XLA
@@ -106,18 +95,11 @@ def partition_rules(param_sharding: str, fsdp_axes=("data",), cfg=None,
                      or cfg.num_heads % model_size == 0)
     kv_head_ok = bool(cfg is None or not model_size
                       or cfg.num_kv_heads % model_size == 0)
-    if head_shard:
-        wq_spec = P(None, mdl) if q_shardable else P(None, None)
-        wo_spec = P(mdl, None) if q_shardable else P(None, None)
-        kv_spec = P(None, None)
-        kvb_spec = P(None)
-        qb_spec = P(mdl) if q_shardable else P(None)
-    else:
-        wq_spec = P(None, mdl) if q_head_ok else P(None, None)
-        wo_spec = P(mdl, None) if q_head_ok else P(None, None)
-        kv_spec = P(None, mdl) if kv_head_ok else P(None, None)
-        kvb_spec = P(mdl) if kv_head_ok else P(None)
-        qb_spec = P(mdl) if q_head_ok else P(None)
+    wq_spec = P(None, mdl) if q_head_ok else P(None, None)
+    wo_spec = P(mdl, None) if q_head_ok else P(None, None)
+    kv_spec = P(None, mdl) if kv_head_ok else P(None, None)
+    kvb_spec = P(mdl) if kv_head_ok else P(None)
+    qb_spec = P(mdl) if q_head_ok else P(None)
     rules = [
         # embeddings / head
         ("embed/w", P(mdl, None)),

@@ -136,7 +136,7 @@ def init_params(key, cfg) -> PyTree:
 # block application
 # ===========================================================================
 def apply_attn(p, x, cfg, positions, *, window, cache=None, cur_pos=None,
-               mesh=None, batch_axes=("data",), fused=False):
+               fused=False):
     """cache: dict(k, v, pos) for decode; returns (y, new_kv or kv-for-prefill).
 
     ``fused=True`` (decode only) routes the cached-attention read plus the
@@ -157,23 +157,6 @@ def apply_attn(p, x, cfg, positions, *, window, cache=None, cur_pos=None,
         k = head_rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-
-    # §Perf O1: pin head-major sharding so GSPMD never reshards k/v inside
-    # the flash chunk loops.  q-heads shard over "model" when divisible; k/v
-    # are repeated to H heads and inherit q's sharding (their params are
-    # replicated under this layout, see partition_rules).
-    if cache is None and cfg.opt_attn_head_shard and mesh is not None:
-        from jax.sharding import PartitionSpec as _P
-        bd = tuple(batch_axes) or None
-        shardable = cfg.num_heads % mesh.shape["model"] == 0
-        hspec = _P(bd, None, "model" if shardable else None, None)
-        G = cfg.num_heads // cfg.num_kv_heads
-        if G > 1:
-            k = jnp.repeat(k, G, axis=2)
-            v = jnp.repeat(v, G, axis=2)
-        q = jax.lax.with_sharding_constraint(q, hspec)
-        k = jax.lax.with_sharding_constraint(k, hspec)
-        v = jax.lax.with_sharding_constraint(v, hspec)
 
     if cache is None:  # train / prefill
         o = attention_any(q, k, v, causal=True, window=window)
@@ -290,8 +273,7 @@ def apply_block(p, x, cfg, block: str, positions, *, mesh=None, batch_axes=("dat
             attn_out, kv = apply_attn(
                 p["attn"], h, cfg, positions, window=window,
                 cache=None if cache is None else cache["attn"],
-                cur_pos=cur_pos, mesh=mesh, batch_axes=batch_axes,
-                fused=fused)
+                cur_pos=cur_pos, fused=fused)
         if block == "hymba_mlp":
             if cache is None:
                 ssm_out = ssm_lib.apply_ssm(p["ssm"], h, cfg)
@@ -422,19 +404,7 @@ class Model:
         for layer_p in params.get("lead", ()):
             x, a, _, _ = block_fn(layer_p, x, "attn_mlp")
             aux_total = aux_total + a
-        if "stack" in params and cfg.opt_unroll_layers:
-            # §Perf: unrolled layers — each FSDP all-gather is a per-layer
-            # slice instead of a full-stack gather inside the scan
-            kvs = []
-            stack = self.stack(params)
-            for i in range(cfg.num_moe_layers):
-                layer_p = jax.tree_util.tree_map(lambda a, i=i: a[i], stack)
-                x, a, kv, _ = block_fn(layer_p, x, cfg.block_pattern[0])
-                aux_total = aux_total + a
-                kvs.append(kv if want_kv else None)
-            kvs = None if not want_kv else jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *kvs)
-        elif "stack" in params:
+        if "stack" in params:
             block = cfg.block_pattern[0]
 
             def scan_body(carry, layer_p):
@@ -685,8 +655,7 @@ class Model:
         For attention architectures the per-layer (k, v) from the forward pass
         become the decode cache (trimmed to the sliding window if set).  For
         SSM/hybrid/xLSTM blocks the recurrent state is rebuilt by the decode
-        path itself (examples use ``init_cache`` + replay); the prefill SHAPE
-        in the dry-run lowers this forward pass, which is the expensive part.
+        path itself (examples use ``init_cache`` + replay).
         """
         cfg = self.cfg
         self._refuse_latent("prefill")

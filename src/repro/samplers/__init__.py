@@ -20,7 +20,6 @@ from repro.samplers.policies import (  # noqa: F401
 )
 from repro.samplers.presets import (  # noqa: F401
     MODES,
-    from_config,
     sghmc,
     sgld,
     svrg,

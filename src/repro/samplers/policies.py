@@ -1,8 +1,8 @@
 """Delay policies: how a commit chooses the stale read point ``X_hat_k``.
 
-A :class:`DelayPolicy` replaces the old loose ``delay_k`` argument (and the
-``ring`` special-case inside ``SGLDState``): ``delay_read(policy)`` owns the
-iterate ring buffer and delegates the read to the policy.
+A :class:`DelayPolicy` decides the read from the realized staleness
+``delay_k`` fed per step: ``delay_read(policy)`` owns the iterate ring
+buffer and delegates the read to the policy.
 
 - :class:`ConstantDelay` — worst-case fixed staleness ``tau`` (theory
   experiments), with the can't-be-staler-than-``k`` warm-up built in.

@@ -10,10 +10,12 @@ is exactly
                   apply_sgld_update()),
             gamma=gamma)
 
-and reproduces the legacy ``SGLDSampler`` trajectories bit-for-bit.  The
-zoo variants reuse the same skeleton: :func:`svrg` swaps the gradient stage
-for the control-variate :func:`~repro.samplers.transforms.svrg_gradients`
-oracle, :func:`sghmc` swaps the commit pair for the momentum
+That chain is the one SGLD step every driver builds (the launcher, the
+examples, the benchmark), run by :class:`~repro.train.engine.Engine` or
+:class:`~repro.cluster.ClusterEngine`.  The zoo variants reuse the same
+skeleton: :func:`svrg` swaps the gradient stage for the control-variate
+:func:`~repro.samplers.transforms.svrg_gradients` oracle, :func:`sghmc`
+swaps the commit pair for the momentum
 :func:`~repro.samplers.transforms.sghmc_update`, and every preset takes
 ``stale_strength`` / ``stale_gamma_scale`` to splice the Chen-et-al.
 :func:`~repro.samplers.transforms.stale_correction` in after the gradient
@@ -185,10 +187,3 @@ def sghmc(mode: str, grad_fn: GradFn, *, gamma=1e-2, sigma: float = 1.0,
                               noise_dtype=noise_dtype))
     return Sampler(transform=chain(*parts), gamma=gamma)
 
-
-def from_config(cfg, grad_fn: GradFn, has_aux: bool = False, *,
-                fused: bool = False) -> Sampler:
-    """Build the preset matching a legacy ``SGLDConfig`` (duck-typed)."""
-    return sgld(cfg.mode, grad_fn, gamma=cfg.gamma, sigma=cfg.sigma,
-                tau=cfg.tau, has_aux=has_aux, fused=fused,
-                noise_dtype=getattr(cfg, "noise_dtype", jnp.float32))

@@ -1,9 +1,9 @@
 """The five sampler-transform primitives behind the paper's read models.
 
-Raw leafwise math (``noise_like`` / ``sgld_apply``) lives here too — it is
-the single source of truth shared by the transforms, the legacy
-``SGLDSampler`` shim, and the launch-stack step builders, which is what
-makes the new presets bit-compatible with the old sampler.
+Raw leafwise math (``noise_like`` / ``sgld_apply``) lives here too: the
+unfused noise and commit that :func:`langevin_noise` and
+:func:`apply_sgld_update` apply, exported for code that needs the bare
+arithmetic (the kernel microbenchmark).
 """
 
 from __future__ import annotations
@@ -58,7 +58,7 @@ def masked_mean(values: PyTree, size: jax.Array) -> PyTree:
 
 
 # ---------------------------------------------------------------------------
-# raw leafwise math (shared with the legacy shim and launch/steps.py)
+# raw leafwise math (the unfused noise and commit)
 # ---------------------------------------------------------------------------
 def noise_like(key: jax.Array, params: PyTree, scale: jnp.ndarray, dtype) -> PyTree:
     """sqrt(2 sigma gamma) * G_k, one independent key per leaf, shard-local."""

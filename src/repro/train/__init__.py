@@ -1,2 +1,2 @@
 from repro.train.engine import Engine, checkpoint_hook, log_hook  # noqa: F401
-from repro.train.loop import make_grad_fn, make_train_step, train_loop  # noqa: F401
+from repro.train.loop import make_grad_fn  # noqa: F401

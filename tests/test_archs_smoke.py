@@ -9,11 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import samplers
 from repro.configs import ARCH_IDS, ShapeConfig, get_reduced
-from repro.core import SGLDConfig
 from repro.data import make_batch
 from repro.models.transformer import Model, init_params, loss_fn
-from repro.train.loop import make_train_step
+from repro.train.loop import make_grad_fn
 
 TRAIN_SHAPE = ShapeConfig("smoke_train", seq_len=64, global_batch=2,
                           kind="train")
@@ -38,11 +38,11 @@ def test_forward_shapes_and_finite(setup):
 
 def test_sgld_train_step_updates_params(setup):
     aid, cfg, model, params = setup
-    sgld = SGLDConfig(mode="sync", gamma=1e-3, sigma=1e-8)
-    sampler, step_fn = make_train_step(model, sgld)
+    sampler = samplers.sgld("sync", make_grad_fn(model), has_aux=True,
+                            gamma=1e-3, sigma=1e-8)
     state = sampler.init(params, jax.random.PRNGKey(2))
     batch = make_batch(cfg, TRAIN_SHAPE, jax.random.PRNGKey(3), "train")
-    new_state, metrics = jax.jit(step_fn)(state, batch, 0)
+    new_state, metrics = jax.jit(sampler.step)(state, batch, 0)
     assert np.isfinite(float(metrics["loss"]))
     # params changed and stayed finite
     diffs = jax.tree_util.tree_map(

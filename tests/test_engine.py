@@ -1,5 +1,5 @@
 """Unified Engine: no per-delay retraces, scan-chunking speedup over the
-per-step host loop, hooks, and train_loop integration."""
+per-step host loop, hooks."""
 
 import os
 import time
@@ -159,29 +159,3 @@ def test_engine_rejects_delays_deeper_than_ring(quad_sampler):
     delays = np.asarray([0, 1, 5, 2] * 10)
     with pytest.raises(ValueError, match="does not fit the iterate ring"):
         engine.run(state, steps=STEPS, delays=delays)
-
-
-def test_train_loop_runs_through_engine():
-    from dataclasses import replace
-
-    from repro.configs import ShapeConfig, get_reduced
-    from repro.core.sgld import SGLDConfig
-    from repro.data import make_batch
-    from repro.models.transformer import Model, init_params
-    from repro.train.loop import train_loop
-
-    cfg = replace(get_reduced("qwen3-4b"), dtype="float32")
-    model = Model(cfg, mesh=None)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    shape = ShapeConfig("t", seq_len=16, global_batch=2, kind="train")
-    delays = np.asarray([0, 1, 2, 1, 0, 2], dtype=np.int32)
-    lines = []
-    state, history = train_loop(
-        model, params, SGLDConfig(mode="consistent", gamma=1e-3, sigma=1e-6,
-                                  tau=2),
-        lambda k: make_batch(cfg, shape, k, "train"), steps=6,
-        key=jax.random.PRNGKey(1), delays=delays, log_every=3,
-        log_fn=lines.append)
-    assert [k for k, _ in history] == [0, 3, 5]
-    assert all(np.isfinite(v) for _, v in history)
-    assert lines  # log hook fired

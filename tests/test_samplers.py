@@ -1,5 +1,6 @@
-"""Composable sampler API: preset-vs-legacy parity (all four modes), chain
-composability, delay policies, fused-vs-unfused commit, ring wraparound."""
+"""Composable sampler API: preset parity with an in-file oracle of the
+paper's update (all four modes), mode/tau validation, chain composability,
+delay policies, fused-vs-unfused commit, ring wraparound."""
 
 import jax
 import jax.numpy as jnp
@@ -77,20 +78,17 @@ def test_preset_matches_legacy_sampler(quad, mode, tau):
                                rtol=2e-5, atol=1e-6)
 
 
-def test_deprecated_shim_delegates_to_presets(quad):
+@pytest.mark.parametrize("mode,tau", [("lockstep", 0), ("consistent", 0),
+                                      ("inconsistent", 0)])
+def test_presets_refuse_unknown_mode_and_stale_read_without_tau(quad, mode,
+                                                                tau):
+    """Mode and tau are validated once, where every preset builds its read
+    stage: an unknown mode, and a stale read with no ring (tau 0), raise
+    before any state exists."""
     grad = lambda p, b: quad.grad(p, b)  # noqa: E731
-    from repro.core import SGLDConfig, SGLDSampler
-
-    with pytest.warns(DeprecationWarning):
-        legacy = SGLDSampler(SGLDConfig(mode="consistent", gamma=GAMMA,
-                                        sigma=SIGMA, tau=4), grad)
-    new = samplers.sgld("consistent", grad, gamma=GAMMA, sigma=SIGMA, tau=4)
-    delays = _delays_for(4, 30)
-    s1 = legacy.init(jnp.zeros(4), jax.random.PRNGKey(2))
-    s2 = new.init(jnp.zeros(4), jax.random.PRNGKey(2))
-    _, t1 = jax.jit(lambda s: legacy.run(s, jnp.zeros((30, 1)), delays))(s1)
-    _, t2 = jax.jit(lambda s: new.run(s, jnp.zeros((30, 1)), delays))(s2)
-    np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
+    for preset in (samplers.sgld, samplers.sghmc):
+        with pytest.raises(ValueError, match=repr(mode)):
+            preset(mode, grad, gamma=GAMMA, sigma=SIGMA, tau=tau)
 
 
 def test_chain_composes_to_gradient_descent(quad):

@@ -6,12 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (
-    Quadratic,
-    SGLDConfig,
-    SGLDSampler,
-    constant_delays,
-)
+from repro import samplers
+from repro.core import Quadratic, constant_delays
 
 SIGMA = 0.5
 GAMMA = 0.01
@@ -25,8 +21,8 @@ def quad():
 
 
 def _run(quad, mode, tau=0, delays=None, steps=N_STEPS, seed=1):
-    cfg = SGLDConfig(mode=mode, gamma=GAMMA, sigma=SIGMA, tau=tau)
-    sampler = SGLDSampler(cfg, lambda p, b: quad.grad(p, b))
+    sampler = samplers.sgld(mode, lambda p, b: quad.grad(p, b), gamma=GAMMA,
+                            sigma=SIGMA, tau=tau)
     state = sampler.init(jnp.zeros(4), jax.random.PRNGKey(seed))
     batches = jnp.zeros((steps, 1))
     if delays is None:
@@ -67,9 +63,9 @@ def test_decreasing_gamma_schedule_converges(quad):
 
     # low temperature: this test checks the schedule mechanics (drift to
     # x*), not the stationary spread — keep estimator noise small
-    cfg = SGLDConfig(mode="sync", gamma=poly_decay(0.1, alpha=0.4, t0=10.0),
-                     sigma=0.02)
-    sampler = SGLDSampler(cfg, lambda p, b: quad.grad(p, b))
+    sampler = samplers.sgld("sync", lambda p, b: quad.grad(p, b),
+                            gamma=poly_decay(0.1, alpha=0.4, t0=10.0),
+                            sigma=0.02)
     state = sampler.init(jnp.zeros(4) + 5.0, jax.random.PRNGKey(2))
     batches = jnp.zeros((N_STEPS, 1))
     delays = jnp.zeros((N_STEPS,), jnp.int32)
@@ -84,8 +80,8 @@ def test_decreasing_gamma_schedule_converges(quad):
 def test_pipeline_equals_one_step_stale_gradient(quad):
     """pipeline mode is exactly W-Con with tau=1 on the gradient sequence:
     with sigma=0 and constant gamma, params_{k+1} = params_k - g(params_{k-1})."""
-    cfg = SGLDConfig(mode="pipeline", gamma=0.1, sigma=0.0)
-    sampler = SGLDSampler(cfg, lambda p, b: quad.grad(p, b))
+    sampler = samplers.sgld("pipeline", lambda p, b: quad.grad(p, b),
+                            gamma=0.1, sigma=0.0)
     state = sampler.init(jnp.ones(4), jax.random.PRNGKey(3))
     # manual reference
     x = jnp.ones(4)
@@ -103,8 +99,8 @@ def test_aux_metrics_surface(quad):
     def grad_with_aux(p, b):
         return quad.grad(p, b), {"loss": quad.value(p, b)}
 
-    cfg = SGLDConfig(mode="sync", gamma=GAMMA, sigma=SIGMA)
-    sampler = SGLDSampler(cfg, grad_with_aux, has_aux=True)
+    sampler = samplers.sgld("sync", grad_with_aux, has_aux=True, gamma=GAMMA,
+                            sigma=SIGMA)
     state = sampler.init(jnp.zeros(4), jax.random.PRNGKey(4))
     state, aux = sampler.step(state, None, 0)
     assert "loss" in aux and np.isfinite(float(aux["loss"]))

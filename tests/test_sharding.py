@@ -1,8 +1,10 @@
 """Sharding integration: runs in a SUBPROCESS with 8 forced host devices so
-the main pytest process keeps seeing 1 device (per the dry-run isolation
-rule).  Verifies that the sharded MoE path equals the local path and that a
-small mesh train step lowers, compiles, and executes."""
+the main pytest process keeps seeing 1 device (the forced devices stay
+isolated).  Verifies that the sharded MoE path equals the local path and that
+``samplers.sgld`` over a microbatched gradient, its parameters sharded on a
+2x4 mesh, commits the losses the unsharded chain commits."""
 
+import functools
 import os
 
 import pytest
@@ -47,36 +49,51 @@ import json
 from dataclasses import replace
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro import samplers
 from repro.configs import get_reduced, ShapeConfig
 from repro.data import make_batch
 from repro.models.common import partition_tree
 from repro.models.transformer import Model, init_params
-from repro.launch.steps import make_sgld_train_step, sanitized_named
+from repro.train.loop import make_grad_fn
 
 from repro.launch.mesh import make_debug_mesh
 mesh = make_debug_mesh(data=2, model=4)
 cfg = replace(get_reduced("qwen3-4b"), dtype="float32")
-shape = ShapeConfig("t", seq_len=64, global_batch=4, kind="train",
-                    num_microbatches=2)
-model = Model(cfg, mesh=mesh, batch_axes=("data",))
+shape = ShapeConfig("t", seq_len=64, global_batch=4, kind="train")
 params = init_params(jax.random.PRNGKey(0), cfg)
 specs = partition_tree(params, cfg.param_sharding, cfg=cfg,
                        model_size=mesh.shape["model"])
-pshard = sanitized_named(mesh, specs, params)
-params = jax.device_put(params, pshard)
-batch = make_batch(cfg, shape, jax.random.PRNGKey(1), "train")
-step = make_sgld_train_step(model, shape, mode="sync", gamma=1e-3, sigma=1e-8)
-with jax.set_mesh(mesh):
-    jstep = jax.jit(step, out_shardings=(pshard, NamedSharding(mesh, P())))
-    new_params, loss = jstep(params, batch, jnp.array([0, 1], jnp.uint32))
-    loss2 = None
-    # unsharded reference
-model0 = Model(cfg, mesh=None)
-step0 = make_sgld_train_step(model0, shape, mode="sync", gamma=1e-3, sigma=1e-8)
-_, loss_ref = jax.jit(step0)(jax.device_get(params), batch,
-                             jnp.array([0, 1], jnp.uint32))
-print(json.dumps({"loss": float(loss), "loss_ref": float(loss_ref),
-                  "finite": bool(np.isfinite(float(loss)))}))
+pshard = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
+                                is_leaf=lambda x: isinstance(x, P))
+sharded = jax.device_put(params, pshard)
+batches = [make_batch(cfg, shape, jax.random.PRNGKey(1 + i), "train")
+           for i in range(3)]
+delays = [0, 1, 2]
+
+
+def losses(mode, model, params):
+    # two microbatches of two sequences: the accumulated oracle
+    sampler = samplers.sgld(mode, make_grad_fn(model, 2), has_aux=True,
+                            gamma=1e-3, sigma=1e-8,
+                            tau=2 if mode == "consistent" else 0)
+    step = jax.jit(sampler.step)
+    state = sampler.init(params, jax.random.PRNGKey(2))
+    out = []
+    for batch, delay in zip(batches, delays):
+        state, aux = step(state, batch, delay)
+        out.append(float(aux["loss"]))
+    return out, bool(all(np.isfinite(np.asarray(x)).all()
+                         for x in jax.tree_util.tree_leaves(state.params)))
+
+
+res = {}
+for mode in ("sync", "pipeline", "consistent"):
+    with jax.set_mesh(mesh):
+        loss, finite = losses(mode, Model(cfg, mesh=mesh, batch_axes=("data",)),
+                              sharded)
+    loss_ref, _ = losses(mode, Model(cfg, mesh=None), params)
+    res[mode] = {"loss": loss, "loss_ref": loss_ref, "finite": finite}
+print(json.dumps(res))
 """
 
 
@@ -99,8 +116,18 @@ def test_sharded_moe_matches_local():
     assert sum(res["loads"][0]) == 4 * 16 * 2, res
 
 
+@functools.lru_cache(maxsize=None)
+def _train_results() -> dict:
+    """One subprocess for every mode: the sharded chains' per-commit losses
+    beside the unsharded ones."""
+    return _run(SCRIPT_TRAIN)
+
+
 @pytest.mark.slow
-def test_sharded_train_step_matches_unsharded_loss():
-    res = _run(SCRIPT_TRAIN)
+@pytest.mark.parametrize("mode", ["sync", "pipeline", "consistent"])
+def test_sharded_train_step_matches_unsharded_loss(mode):
+    res = _train_results()[mode]
     assert res["finite"], res
-    assert abs(res["loss"] - res["loss_ref"]) < 5e-3, res
+    assert len(res["loss"]) == len(res["loss_ref"]) == 3, res
+    for loss, ref in zip(res["loss"], res["loss_ref"]):
+        assert abs(loss - ref) < 5e-3, res

@@ -12,13 +12,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import samplers
 from repro.core import (
     PolyRegression,
     ProblemConstants,
     Quadratic,
     RICA,
-    SGLDConfig,
-    SGLDSampler,
     WorkerModel,
     gamma_eps_w2,
     simulate_async,
@@ -34,14 +33,14 @@ def reg():
 def _run_regression(reg, mode, tau, steps=8000, sigma=1e-3, batch=256,
                     seed=0):
     gamma = 2e-4
-    cfg = SGLDConfig(mode=mode, gamma=gamma, sigma=sigma,
-                     tau=tau if mode in ("consistent", "inconsistent") else 0)
 
     def grad(p, key):
         batch_data = reg.sample_batch(key, batch)
         return jax.grad(reg.value)(p, batch_data)
 
-    sampler = SGLDSampler(cfg, grad)
+    sampler = samplers.sgld(
+        mode, grad, gamma=gamma, sigma=sigma,
+        tau=tau if mode in ("consistent", "inconsistent") else 0)
     mu, cov, _ = reg.posterior_moments(sigma=sigma)
     state = sampler.init(mu + 0.5, jax.random.PRNGKey(seed))
     keys = jax.random.split(jax.random.PRNGKey(seed + 1), steps)
@@ -81,12 +80,11 @@ def test_rica_objective_decreases():
     """Paper §3.3: SGLD on RICA drives the (non-convex) objective down."""
     rica = RICA(patch_dim=64, num_features=32)
     w0 = rica.init_params(jax.random.PRNGKey(0))
-    cfg = SGLDConfig(mode="consistent", gamma=2e-3, sigma=1e-6, tau=4)
 
     def grad(p, key):
         return rica.grad(p, rica.sample_batch(key, 256))
 
-    sampler = SGLDSampler(cfg, grad)
+    sampler = samplers.sgld("consistent", grad, gamma=2e-3, sigma=1e-6, tau=4)
     state = sampler.init(w0, jax.random.PRNGKey(1))
     keys = jax.random.split(jax.random.PRNGKey(2), 400)
     from repro.core import constant_delays
@@ -111,9 +109,8 @@ def test_theory_prescription_reaches_epsilon():
                          w2sq_0=float(jnp.sum(quad.x_star**2)))
     gamma = gamma_eps_w2(c, eps)
     n = min(60_000, 2 * int(np.ceil(np.log(4 * c.w2sq_0 / eps) / (gamma * c.m))))
-    cfg = SGLDConfig(mode="consistent", gamma=float(gamma), sigma=sigma,
-                     tau=tau)
-    sampler = SGLDSampler(cfg, lambda p, b: quad.grad(p, b))
+    sampler = samplers.sgld("consistent", lambda p, b: quad.grad(p, b),
+                            gamma=float(gamma), sigma=sigma, tau=tau)
     from repro.core import constant_delays
     delays = jnp.asarray(constant_delays(tau, n).delays)
     batches = jnp.zeros((n, 1))
